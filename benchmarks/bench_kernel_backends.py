@@ -213,23 +213,19 @@ def run_streaming_serving(n_q=32, n_docs=256, m=128, l=32, dim=128, k=10):
 GRID = dict(n_q=8, n_docs=96, m=32, l=8, dim=32, k=10, hosts=2)
 
 
-def run_grid_serving(**shape):
-    """Multi-host placement comparison (DESIGN_BACKENDS.md §Placement):
-    the flat single-tier candidates layout vs the 2-D grid (buckets
-    pinned to host groups, per-group merge + cross-group candidate
-    exchange), on a 4-device forced grid in a subprocess.  Records q/s
-    for both layouts, the wire bytes the candidate exchange moves
-    (total and the cross-host share — the number placement exists to
-    shrink), a results-identical bit against the single-device oracle,
-    and whether the compiled per-group HLO is free of corpus-sized
-    tensors.  ``--check`` gates the parity and HLO bits.
+# What a forced-device child result is: the host CPU standing in for a
+# 4-chip grid.  Never a device number.
+CPU_REHEARSAL = "cpu rehearsal: 4 forced host-platform devices"
 
-    Returns ``{"skipped": reason}`` when the platform cannot form a
-    >= 2x1 grid (e.g. a TPU backend with < 4 devices, where the forced
-    host-platform flag does not apply)."""
+
+def _cpu_grid_child(flag: str, shape: dict) -> dict:
+    """Run this module's ``flag`` worker in a child on 4 forced CPU
+    devices (``JAX_PLATFORMS=cpu``: the child never reaches for an
+    accelerator the parent may hold) and return its result, labelled
+    as a CPU rehearsal."""
     import subprocess
-    shape = GRID | shape
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.abspath(os.path.join(os.path.dirname(__file__),
@@ -239,28 +235,50 @@ def run_grid_serving(**shape):
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_kernel_backends",
-         "--grid-worker", json.dumps(shape)],
+         flag, json.dumps(shape)],
         env=env, capture_output=True, text=True, timeout=540)
     if out.returncode != 0:
-        raise RuntimeError(f"grid bench worker failed:\n{out.stderr[-2000:]}")
+        raise RuntimeError(f"{flag} bench child failed:\n"
+                           f"{out.stderr[-2000:]}")
     line = [ln for ln in out.stdout.splitlines()
-            if ln.startswith("GRID_RESULT ")][-1]
-    return json.loads(line[len("GRID_RESULT "):])
+            if ln.startswith("RESULT ")][-1]
+    return dict(json.loads(line[len("RESULT "):]), platform=CPU_REHEARSAL)
 
 
-def _grid_worker(shape: dict) -> dict:
-    """Runs inside the forced-device subprocess; prints one
-    ``GRID_RESULT {json}`` line."""
-    import re as re_
-
-    from repro.launch.mesh import default_serve_hosts, make_serve_mesh
-    from repro.serve.retrieval import topk_search, topk_search_group
-    from repro.sharding import PlacementPlan, axis_rules, serve_rules
-
+def _grid_hosts(shape: dict) -> int:
+    from repro.launch.mesh import default_serve_hosts
     hosts = int(shape["hosts"])
     n_dev = len(jax.devices())
     if n_dev < 2 * hosts or default_serve_hosts() < 2:
-        return {"skipped": f"needs {2 * hosts} devices, have {n_dev}"}
+        raise RuntimeError(f"the grid needs {2 * hosts} devices, "
+                           f"{n_dev} found")
+    return hosts
+
+
+def run_grid_serving(**shape):
+    """Multi-host placement comparison (DESIGN_BACKENDS.md §Placement):
+    the flat single-tier candidates layout vs the 2-D grid (buckets
+    pinned to host groups, per-group merge + cross-group candidate
+    exchange), on a 4-device forced CPU grid in a child process (a
+    rehearsal, labelled so).  Records q/s for both layouts, the wire
+    bytes the candidate exchange moves (total and the cross-host share
+    — the number placement exists to shrink), a results-identical bit
+    against the single-device oracle, and whether the compiled
+    per-group HLO is free of corpus-sized tensors.  ``--check`` gates
+    the parity and HLO bits."""
+    return _cpu_grid_child("--grid-worker", GRID | shape)
+
+
+def _grid_worker(shape: dict) -> dict:
+    """Runs inside the forced-device child; returns its result."""
+    import re as re_
+
+    from repro.launch.mesh import make_serve_mesh
+    from repro.serve.retrieval import topk_search, topk_search_group
+    from repro.sharding import PlacementPlan, axis_rules, serve_rules
+
+    hosts = _grid_hosts(shape)
+    n_dev = len(jax.devices())
     n_q, n_docs, m, l, dim, k = (shape[x] for x in
                                  ("n_q", "n_docs", "m", "l", "dim", "k"))
     key = jax.random.PRNGKey(0)
@@ -334,41 +352,20 @@ def run_fault_tolerance(**shape):
     the replica programs' compile), post-failover steady-state q/s, a
     parity bit (failover results bit-identical to the no-failure
     oracle), and the degraded coverage fraction an unreplicated plan
-    reports after the same loss.  ``--check`` gates the parity bit and
-    the degraded-coverage contract."""
-    import subprocess
-    shape = GRID | shape
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                      os.pardir))]
-        + [os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                        os.pardir, "src"))]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_kernel_backends",
-         "--fault-worker", json.dumps(shape)],
-        env=env, capture_output=True, text=True, timeout=540)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"fault bench worker failed:\n{out.stderr[-2000:]}")
-    line = [ln for ln in out.stdout.splitlines()
-            if ln.startswith("FAULT_RESULT ")][-1]
-    return json.loads(line[len("FAULT_RESULT "):])
+    reports after the same loss, on the same forced CPU grid.
+    ``--check`` gates the parity bit and the degraded-coverage
+    contract."""
+    return _cpu_grid_child("--fault-worker", GRID | shape)
 
 
 def _fault_worker(shape: dict) -> dict:
-    """Runs inside the forced-device subprocess; prints one
-    ``FAULT_RESULT {json}`` line."""
-    from repro.launch.mesh import default_serve_hosts, make_serve_mesh
+    """Runs inside the forced-device child; returns its result."""
+    from repro.launch.mesh import make_serve_mesh
     from repro.serve import health
     from repro.sharding import PlacementPlan, axis_rules, serve_rules
 
-    hosts = int(shape["hosts"])
+    hosts = _grid_hosts(shape)
     n_dev = len(jax.devices())
-    if n_dev < 2 * hosts or default_serve_hosts() < 2:
-        return {"skipped": f"needs {2 * hosts} devices, have {n_dev}"}
     n_q, n_docs, m, l, dim, k = (shape[x] for x in
                                  ("n_q", "n_docs", "m", "l", "dim", "k"))
     key = jax.random.PRNGKey(0)
@@ -1114,9 +1111,6 @@ def check_last(path: str = OUT_PATH) -> None:
     if grid is None:
         raise SystemExit(f"{path}: last entry predates grid placement "
                          "serving; re-run the bench")
-    if grid.get("skipped"):
-        print(f"grid placement smoke SKIPPED: {grid['skipped']}")
-        return
     if not grid.get("results_identical", False):
         raise SystemExit(
             "PARITY REGRESSION: grid-placed serving diverged from the "
@@ -1135,9 +1129,6 @@ def check_last(path: str = OUT_PATH) -> None:
     if ft is None:
         raise SystemExit(f"{path}: last entry predates fault-tolerant "
                          "serving; re-run the bench")
-    if ft.get("skipped"):
-        print(f"fault tolerance smoke SKIPPED: {ft['skipped']}")
-        return
     if not ft.get("parity_failover_identical", False):
         raise SystemExit(
             "FAILOVER REGRESSION: replicated serving after one lost host "
@@ -1285,41 +1276,34 @@ def main():
         f"fraction={routed['fraction_buckets_nprobe']:.2f};"
         f"recall={routed['recall_nprobe']:.3f};"
         f"bounded_exact={routed['bounded_exact']}")
-    if grid.get("skipped"):
-        common.csv_line("kernel_backends/serving_grid_skipped", 0.0,
-                        f"reason={grid['skipped']}")
-    else:
-        for name in ("flat", "grid"):
-            common.csv_line(f"kernel_backends/serving_placement_{name}",
-                            1e6 / grid[name], f"q_per_s={grid[name]:.2f}")
-        grid_ok = (grid["results_identical"]
-                   and grid["hlo_no_corpus_matrix"])
-        common.csv_line(
-            "kernel_backends/CLAIM_grid_placement_shrinks_cross_host_bytes",
-            0.0,
-            f"holds={grid_ok};cross_host_bytes_ratio="
-            f"{grid['cross_host_bytes_ratio_flat_over_grid']:.1f}x;"
-            f"parity={grid['results_identical']};"
-            f"hlo_clean={grid['hlo_no_corpus_matrix']}")
-    if fault.get("skipped"):
-        common.csv_line("kernel_backends/serving_fault_skipped", 0.0,
-                        f"reason={fault['skipped']}")
-    else:
-        common.csv_line("kernel_backends/serving_replicated",
-                        1e6 / fault["replicated"],
-                        f"q_per_s={fault['replicated']:.2f}")
-        common.csv_line("kernel_backends/serving_failover_recovery",
-                        fault["failover_recovery_s"] * 1e6,
-                        f"first_query_after_loss_s="
-                        f"{fault['failover_recovery_s']:.3f}")
-        fault_ok = (fault["parity_failover_identical"]
-                    and 0.0 < fault["degraded_coverage"] < 1.0)
-        common.csv_line(
-            "kernel_backends/CLAIM_replicated_failover_bit_identical",
-            0.0,
-            f"holds={fault_ok};"
-            f"parity={fault['parity_failover_identical']};"
-            f"degraded_coverage={fault['degraded_coverage']:.3f}")
+    for name in ("flat", "grid"):
+        common.csv_line(f"kernel_backends/serving_placement_{name}",
+                        1e6 / grid[name],
+                        f"q_per_s={grid[name]:.2f};{CPU_REHEARSAL}")
+    grid_ok = (grid["results_identical"]
+               and grid["hlo_no_corpus_matrix"])
+    common.csv_line(
+        "kernel_backends/CLAIM_grid_placement_shrinks_cross_host_bytes",
+        0.0,
+        f"holds={grid_ok};cross_host_bytes_ratio="
+        f"{grid['cross_host_bytes_ratio_flat_over_grid']:.1f}x;"
+        f"parity={grid['results_identical']};"
+        f"hlo_clean={grid['hlo_no_corpus_matrix']}")
+    common.csv_line("kernel_backends/serving_replicated",
+                    1e6 / fault["replicated"],
+                    f"q_per_s={fault['replicated']:.2f};{CPU_REHEARSAL}")
+    common.csv_line("kernel_backends/serving_failover_recovery",
+                    fault["failover_recovery_s"] * 1e6,
+                    f"first_query_after_loss_s="
+                    f"{fault['failover_recovery_s']:.3f};{CPU_REHEARSAL}")
+    fault_ok = (fault["parity_failover_identical"]
+                and 0.0 < fault["degraded_coverage"] < 1.0)
+    common.csv_line(
+        "kernel_backends/CLAIM_replicated_failover_bit_identical",
+        0.0,
+        f"holds={fault_ok};"
+        f"parity={fault['parity_failover_identical']};"
+        f"degraded_coverage={fault['degraded_coverage']:.3f}")
 
     entry = {
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
@@ -1376,15 +1360,9 @@ def main():
         "routed_serving": routed,
         "claim_routed_serving_sublinear_high_recall": bool(routed_ok),
         "grid_serving": grid,
-        "claim_grid_placement_parity_and_clean_hlo": bool(
-            grid.get("skipped")
-            or (grid["results_identical"]
-                and grid["hlo_no_corpus_matrix"])),
+        "claim_grid_placement_parity_and_clean_hlo": bool(grid_ok),
         "fault_tolerance": fault,
-        "claim_replicated_failover_bit_identical": bool(
-            fault.get("skipped")
-            or (fault["parity_failover_identical"]
-                and 0.0 < fault["degraded_coverage"] < 1.0)),
+        "claim_replicated_failover_bit_identical": bool(fault_ok),
     }
     append_entry(entry)
 
@@ -1393,10 +1371,10 @@ if __name__ == "__main__":
     argv = sys.argv[1:]
     if "--grid-worker" in argv:
         shape = json.loads(argv[argv.index("--grid-worker") + 1])
-        print("GRID_RESULT " + json.dumps(_grid_worker(shape)))
+        print("RESULT " + json.dumps(_grid_worker(shape)))
     elif "--fault-worker" in argv:
         shape = json.loads(argv[argv.index("--fault-worker") + 1])
-        print("FAULT_RESULT " + json.dumps(_fault_worker(shape)))
+        print("RESULT " + json.dumps(_fault_worker(shape)))
     elif "--check" in argv:
         check_last()
     else:

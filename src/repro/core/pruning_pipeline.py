@@ -63,12 +63,13 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backend as backend_lib
 from repro.core import voronoi
-from repro.core.tuning import _pow2_at_least
+from repro.core.tuning import _pow2_at_least, pruning_docs_per_block
 
 __all__ = [
     "Bucket",
@@ -166,7 +167,6 @@ def _bucket_order_sharded(e, k, samples, mesh, **kw):
     outputs shard straight back over ``data``.  Per-document pruning
     touches no cross-document state, so this is bit-identical to the
     unsharded dispatch."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_b = e.shape[0]
@@ -179,12 +179,28 @@ def _bucket_order_sharded(e, k, samples, mesh, **kw):
     def body(eb, kb, s):
         return voronoi.pruning_order_batch(eb, kb, s, **kw)
 
-    r, er, o = shard_map(body, mesh=mesh,
-                         in_specs=(P("data", None, None), P("data", None),
-                                   P(None, None)),
-                         out_specs=(P("data", None),) * 3,
-                         check_rep=False)(e, k, samples)
+    r, er, o = jax.shard_map(body, mesh=mesh,
+                             in_specs=(P("data", None, None),
+                                       P("data", None), P(None, None)),
+                             out_specs=(P("data", None),) * 3,
+                             check_vma=False)(e, k, samples)
     return r[:n_b], er[:n_b], o[:n_b]
+
+
+def _doc_blocks(plan, n_samples: int):
+    """Split each bucket into dispatch blocks of the tuner's
+    ``pruning_docs_per_block``: ``(block, rows)`` pairs, ``rows`` being
+    the document count the block runs at (the last block of a split
+    bucket is padded with all-masked documents, so every block of a
+    width shares one program)."""
+    for bucket in plan:
+        n = len(bucket.indices)
+        per = pruning_docs_per_block(n_samples, bucket.width)
+        if not per or per >= n:
+            yield bucket, n
+            continue
+        for lo in range(0, n, per):
+            yield Bucket(bucket.width, bucket.indices[lo:lo + per]), per
 
 
 def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
@@ -203,7 +219,8 @@ def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
     pruning several sample sets over one corpus).  ``sharded`` selects
     the ``shard_map``-over-``data`` bucket compute (:func:`_data_mesh`
     policy: auto under a data mesh, forced with ``True``); the plan
-    itself is always computed once, host-side.
+    itself is always computed once, host-side; each bucket dispatches
+    in blocks that fit device memory (:func:`_doc_blocks`).
     """
     n_docs, m = d_masks.shape
     order_len = _order_len(m, step_size)
@@ -231,10 +248,14 @@ def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
     # overlaps bucket i's compute with bucket i+1's staging — the
     # double-buffered loop), then gather.
     in_flight = []
-    for bucket in plan:
+    for bucket, rows in _doc_blocks(plan, samples.shape[0]):
         idx = jnp.asarray(bucket.indices)
         e = jnp.take(d_embs, idx, axis=0)[:, :bucket.width]
         k = jnp.take(d_masks, idx, axis=0)[:, :bucket.width]
+        pad = rows - len(bucket.indices)
+        if pad:
+            e = jnp.pad(e, ((0, pad), (0, 0), (0, 0)))
+            k = jnp.pad(k, ((0, pad), (0, 0)))
         kw = dict(step_size=step_size, fast=fast, bf16_scores=bf16_scores,
                   shortlist=shortlist, backend=backend)
         if mesh is not None:
@@ -248,6 +269,8 @@ def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
             out = _bucket_order_sharded(e, k, samples, mesh, **kw)
         else:
             out = voronoi.pruning_order_batch(e, k, samples, **kw)
+        if pad:
+            out = tuple(o[:len(bucket.indices)] for o in out)
         in_flight.append((bucket, out))
     for bucket, out in in_flight:
         _scatter_bucket(ranks, errs, orders, bucket, out, m)

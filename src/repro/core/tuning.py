@@ -58,6 +58,7 @@ __all__ = [
     "dump_cache",
     "heuristic_config",
     "load_cache",
+    "pruning_docs_per_block",
     "shape_key",
     "tune",
 ]
@@ -71,6 +72,10 @@ _CACHE_FORMAT = 2
 # Per-core VMEM is ~16 MB on current TPUs; budget half of it so the
 # pipelined double-buffering of grid blocks still fits.
 DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+# The serving kernels' VMEM model (`_tpu_serving_block_docs`) already
+# counts double-buffering and in-kernel temporaries, so it is held to
+# three quarters of Mosaic's 16 MiB default scoped-VMEM limit on v5e.
+SERVING_VMEM_BUDGET = 12 * 1024 * 1024
 # Off-TPU the kernels run through the Pallas interpreter: there is no
 # VMEM to respect, block buffers live in host cache, and larger blocks
 # amortize per-launch interpreter overhead — so the working-set bound is
@@ -181,6 +186,51 @@ def _pruning_heuristic(shape: dict, platform: str,
                         shortlist=k, rescan_every=rescan).validate()
 
 
+# Device memory one vmapped pruning dispatch may hold on TPU.  The
+# shortlist scan keeps ~12 bytes per (sample, token) of every document
+# in flight (compiled for v5e at 10k samples x 180 tokens it holds 9.4),
+# so a 10k-sample job at width 180 runs 256 documents per dispatch.
+PRUNING_HBM_BUDGET = 6 * 1024 ** 3
+
+
+def pruning_docs_per_block(n_samples: int, width: int,
+                           platform: str | None = None) -> int | None:
+    """Documents per vmapped pruning dispatch of one ``width``-token
+    bucket: on TPU the largest power of two whose working set fits
+    :data:`PRUNING_HBM_BUDGET`; ``None`` (the whole bucket in one
+    dispatch) elsewhere, where host memory is the bound."""
+    if (platform or jax.default_backend()) != "tpu":
+        return None
+    per_doc = 12 * max(n_samples, 1) * max(width, 1)
+    docs = 1
+    while 2 * docs * per_doc <= PRUNING_HBM_BUDGET:
+        docs *= 2
+    return docs
+
+
+def _tpu_serving_block_docs(n_q: int, m: int, l: int, dim: int,
+                            vmem_budget: int) -> int:
+    """Largest power-of-two doc block in [8, 128] whose compiled MaxSim
+    kernel fits VMEM.  Per doc token (m padded to the kernels' sublane
+    tile) the kernel holds the double-buffered f32 doc row, the three
+    bf16 parts of a ``Precision.HIGHEST`` matmul operand, and one f32
+    score chunk plus its masked copy, ``SCORE_LANES`` (query, token)
+    columns wide at most.  The model reproduces, at dim 128, m 32..256
+    and n_q 1..32, the largest block that compiles for v5e; the floor
+    of 8 is the kernels' sublane tile (``colbert_maxsim.doc_block``)."""
+    from repro.kernels.colbert_maxsim.colbert_maxsim import (SCORE_LANES,
+                                                              SUBLANES)
+    tokens = _round_up(m, SUBLANES)
+    lanes = _round_up(min(n_q * l, SCORE_LANES), 128)
+    per_token = 4 * (2 * dim + 2 * lanes) + 3 * 2 * dim
+    fixed = 2 * 4 * n_q * l * dim
+    block_docs = 128
+    while block_docs > SUBLANES and (
+            fixed + block_docs * tokens * per_token > vmem_budget):
+        block_docs //= 2
+    return block_docs
+
+
 def _serving_heuristic(shape: dict, platform: str,
                        vmem_budget: int) -> KernelConfig:
     n_q = int(shape.get("n_q", 16))
@@ -203,15 +253,18 @@ def _serving_heuristic(shape: dict, platform: str,
     n_local = -(-n_docs // n_shards)
 
     block_q = min(_pow2_at_least(max(n_q, 1)), 32)
-    # Doc block: largest power of two whose (docs + queries + scores)
-    # f32 tiles fit the budget; bigger blocks amortize kernel launches
-    # and feed the MXU larger matmuls.
-    block_docs = 128
-    while block_docs > 4 and 4 * (block_docs * m * dim
-                                  + block_q * l * dim
-                                  + block_docs * m * block_q * l
-                                  ) > vmem_budget:
-        block_docs //= 2
+    if platform == "tpu":
+        block_docs = _tpu_serving_block_docs(block_q, m, l, dim, vmem_budget)
+    else:
+        # Doc block: largest power of two whose (docs + queries + scores)
+        # f32 tiles fit the budget; bigger blocks amortize kernel
+        # launches and feed the MXU larger matmuls.
+        block_docs = 128
+        while block_docs > 4 and 4 * (block_docs * m * dim
+                                      + block_q * l * dim
+                                      + block_docs * m * block_q * l
+                                      ) > vmem_budget:
+            block_docs //= 2
     block_docs = min(block_docs, _pow2_at_least(max(n_local, 1)))
 
     # Streaming chunk: the doc slab scored-then-reduced per merge step.
@@ -247,8 +300,9 @@ def heuristic_config(kind: str, *, platform: str | None = None,
     amortize launch overhead)."""
     platform = platform or jax.default_backend()
     if vmem_budget is None:
-        vmem_budget = (DEFAULT_VMEM_BUDGET if platform == "tpu"
-                       else INTERPRET_WORKING_SET_BUDGET)
+        vmem_budget = (INTERPRET_WORKING_SET_BUDGET if platform != "tpu"
+                       else SERVING_VMEM_BUDGET if kind == "serving"
+                       else DEFAULT_VMEM_BUDGET)
     if kind == "pruning":
         return _pruning_heuristic(shape, platform, vmem_budget)
     if kind == "serving":

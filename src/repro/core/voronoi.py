@@ -700,12 +700,11 @@ def _global_keep_masks_sharded(ranks, errs, d_masks, keep_fraction, *,
         pruned = (bits < t) | (eq & (eq_rank < take))
         return dm & ~pruned.reshape(dm.shape)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    keep = shard_map(body, mesh=mesh,
-                     in_specs=(P(axis, None),) * 3,
-                     out_specs=P(axis, None),
-                     check_rep=False)(ranks, errs, d_masks)
+    keep = jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis, None),) * 3,
+                         out_specs=P(axis, None),
+                         check_vma=False)(ranks, errs, d_masks)
     return keep[:n_docs]
 
 

@@ -95,7 +95,10 @@ def _kernel(s_ref, t_ref, alive_ref, best_ref, second_ref, bi_ref, si_ref):
         # the loser-of-bests index i_old comes from an earlier tile (<=
         # own2's current-tile index) so ties take it; when the old state
         # won, own2_i = si_old is the earlier one so ties keep it.
-        take_lose = jnp.where(new_wins, lose1 >= own2, lose1 > own2)
+        # (boolean algebra, not a select of two i1 vectors: Mosaic cannot
+        # truncate the select's i8 result back to i1)
+        take_lose = ((new_wins & (lose1 >= own2))
+                     | (~new_wins & (lose1 > own2)))
         s_new = jnp.where(take_lose, lose1, own2)
         si_new = jnp.where(take_lose, lose1_i, own2_i)
         best_ref[...] = b_new

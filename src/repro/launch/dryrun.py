@@ -23,13 +23,13 @@ import traceback         # noqa: E402
 import jax               # noqa: E402
 import zstandard         # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
 from repro import configs                      # noqa: E402
-from repro.launch import roofline, steps       # noqa: E402
+from repro.launch import compile_cache, roofline, steps  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
+# The production mesh is compiled for, not run on: roofline terms use
+# the published peaks of the chip it models.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "EXPERIMENTS", "dryrun")
 
@@ -72,7 +72,8 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
                 f.write(zstandard.ZstdCompressor(level=6).compress(
                     hlo_text.encode()))
             analysis = roofline.analyze(compiled, hlo_text,
-                                        cell.model_flops_per_step, n_chips)
+                                        cell.model_flops_per_step, n_chips,
+                                        TARGET_DEVICE_KIND)
         record.update(status="ok", lower_s=round(t_lower, 2),
                       compile_s=round(t_compile, 2), analysis=analysis)
         if verbose:
@@ -108,6 +109,7 @@ def main():
     ap.add_argument("--reanalyze", action="store_true",
                     help="recompute roofline terms from saved HLO")
     args = ap.parse_args()
+    compile_cache.enable()
 
     os.makedirs(OUT_DIR, exist_ok=True)
     targets: list[tuple[str, str]] = []
@@ -148,7 +150,7 @@ def main():
                 parsed = roofline.parse_hlo_costs(text)
                 terms = roofline.roofline_terms(
                     parsed["flops"], parsed["hbm_bytes"],
-                    parsed["collective_bytes"])
+                    parsed["collective_bytes"], TARGET_DEVICE_KIND)
                 rec["analysis"].update(
                     hlo_flops_per_chip=parsed["flops"],
                     hlo_bytes_per_chip=parsed["hbm_bytes"],

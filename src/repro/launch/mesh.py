@@ -9,12 +9,22 @@ import; everything else sees the real device count).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding layer
+    places arrays by ``with_sharding_constraint`` and ``shard_map``
+    specs, which is Auto-axis semantics (``make_mesh`` now defaults to
+    Explicit axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
@@ -22,7 +32,7 @@ def make_host_mesh():
     smoke tests and CPU examples (usually 1x1)."""
     n = len(jax.devices())
     data = max(1, n // 1)
-    return jax.make_mesh((data, 1), ("data", "model"))
+    return auto_mesh((data, 1), ("data", "model"))
 
 
 def make_serve_mesh(hosts: int = 1):
@@ -45,12 +55,12 @@ def make_serve_mesh(hosts: int = 1):
     """
     n = max(1, len(jax.devices()))
     if hosts <= 1:
-        return jax.make_mesh((1, n), ("data", "model"))
+        return auto_mesh((1, n), ("data", "model"))
     if n % hosts:
         raise ValueError(
             f"make_serve_mesh(hosts={hosts}): {n} devices do not divide "
             f"into {hosts} host groups")
-    return jax.make_mesh((hosts, n // hosts), ("hosts", "candidates"))
+    return auto_mesh((hosts, n // hosts), ("hosts", "candidates"))
 
 
 def default_serve_hosts() -> int:

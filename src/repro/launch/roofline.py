@@ -12,17 +12,38 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 with ring-transfer multipliers (all-reduce counts 2x its operand, an
 all-gather counts its full output).
 
-Hardware model (TPU v5e-class, per chip): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Per-chip peaks come from :data:`PEAKS`, keyed by the ``device_kind``
+JAX reports; a kind that is not in the table is an error, never a
+default.
 """
 
 from __future__ import annotations
 
 import re
+from typing import NamedTuple
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link
+
+class Peaks(NamedTuple):
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    link_bw: float           # ICI bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+# (50 GB/s each).  JAX reports a v5e chip as "TPU v5 lite".
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Published per-chip peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -262,11 +283,12 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-def roofline_terms(flops: float, bytes_accessed: float,
-                   coll_bytes: float) -> dict:
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll_bytes / LINK_BW
+def roofline_terms(flops: float, bytes_accessed: float, coll_bytes: float,
+                   device_kind: str) -> dict:
+    peak = peaks_for(device_kind)
+    compute_s = flops / peak.flops
+    memory_s = bytes_accessed / peak.hbm_bw
+    collective_s = coll_bytes / peak.link_bw
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dom = max(terms, key=terms.get)
@@ -278,7 +300,7 @@ def roofline_terms(flops: float, bytes_accessed: float,
 
 
 def analyze(compiled, lowered_text: str | None, model_flops: float,
-            n_chips: int) -> dict:
+            n_chips: int, device_kind: str) -> dict:
     cost = compiled.cost_analysis()
     if isinstance(cost, list):                      # older jax returns [dict]
         cost = cost[0]
@@ -302,7 +324,7 @@ def analyze(compiled, lowered_text: str | None, model_flops: float,
     flops = max(parsed["flops"], raw_flops)
     byts = max(parsed["hbm_bytes"], raw_bytes)
     coll_total = parsed["collective_bytes"]
-    terms = roofline_terms(flops, byts, coll_total)
+    terms = roofline_terms(flops, byts, coll_total, device_kind)
     useful = model_flops / (flops * n_chips) if flops > 0 else 0.0
     return {
         "hlo_flops_per_chip": flops,

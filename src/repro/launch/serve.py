@@ -16,6 +16,9 @@
                    artifact sidecar) prunes whole capacity buckets per
                    query before any document is scored, and the run
                    reports recall@k against the exhaustive sweep.
+                   --preset full runs the published ColBERT widths
+                   (12L/768, out_dim 128, doc_len 180) with seeded
+                   random weights over --n-docs synthetic documents.
   --arch <lm>    : KV-cache decode loop on the smoke config
 """
 
@@ -34,9 +37,10 @@ from repro import configs
 from repro import sharding as shlib
 from repro.core import backend as backend_lib
 from repro.core import metrics
-from repro.core import pruning_pipeline
+from repro.core import pruning_pipeline, voronoi
 from repro.core.sampling import sample_sphere
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import colbert as colbert_lib
 from repro.models import transformer as tfm
@@ -44,6 +48,91 @@ from repro.serve import health, index_io
 from repro.serve import mutation as mutation_lib
 from repro.serve.retrieval import RetrievalServer, TokenIndex, topk_search
 from repro.train import checkpoint
+
+
+PRESETS = ("smoke", "full")
+# Corpus size when --n-docs is not given.
+DEFAULT_N_DOCS = {"smoke": 256, "full": 4096}
+# Documents per jitted encoder call: one compiled shape for the corpus.
+ENCODE_BATCH = 256
+
+
+def load_model(preset: str = "smoke", seed: int = 0,
+               ckpt_dir: str | None = None):
+    """``(cfg, params)`` of the ColBERT encoder at ``preset`` width,
+    weights drawn from ``seed`` (or restored from ``ckpt_dir``)."""
+    entry = configs.get("colbert")
+    cfg = entry.config if preset == "full" else entry.smoke
+    params = colbert_lib.init_params(jax.random.PRNGKey(seed), cfg)
+    if ckpt_dir:
+        _, restored = checkpoint.restore_latest(
+            ckpt_dir, {"params": params, "opt": None, "step": None})
+        if restored is not None:
+            params = restored["params"]
+    return cfg, params
+
+
+def n_samples_for(preset: str) -> int:
+    """Voronoi sample count: the ``prune_index`` shape at full width."""
+    if preset == "full":
+        return configs.get("colbert").shapes["prune_index"].dims["n_samples"]
+    return 2048
+
+
+def encode_corpus(params, cfg, doc_ids, batch: int = ENCODE_BATCH):
+    """Encode token-id documents in fixed-shape jitted batches (the last
+    one zero-padded, i.e. all-masked) -> ``(d_emb (n, m, out_dim) f32,
+    d_mask (n, m) bool)``; the index stores fp32 whatever the encoder
+    computes in."""
+    enc = jax.jit(lambda p, ids: colbert_lib.encode_docs(p, cfg, ids))
+    ids = np.asarray(doc_ids)
+    n = ids.shape[0]
+    b = max(1, min(batch, n))
+    embs, masks = [], []
+    for lo in range(0, n, b):
+        chunk = ids[lo:lo + b]
+        if len(chunk) < b:
+            chunk = np.pad(chunk, ((0, b - len(chunk)), (0, 0)))
+        e, mk = enc(params, jnp.asarray(chunk))
+        embs.append(e.astype(jnp.float32))
+        masks.append(mk)
+    return jnp.concatenate(embs)[:n], jnp.concatenate(masks)[:n]
+
+
+def encode_queries(params, cfg, q_ids):
+    """Encode a query batch (ColBERT [MASK] augmentation) in one jitted
+    call -> ``(n_q, query_len, out_dim)`` f32."""
+    enc = jax.jit(lambda p, ids: colbert_lib.encode_queries(p, cfg, ids)[0])
+    return enc(params, jnp.asarray(q_ids)).astype(jnp.float32)
+
+
+def prune_index(d_emb, d_mask, keep_fraction: float, *, n_samples: int,
+                backend: str | None = None) -> TokenIndex:
+    """Voronoi-prune a corpus to ``keep_fraction`` of its tokens under a
+    corpus-wide budget: length-bucketed per-document orders, then the
+    global merge (``pruning_pipeline.prune_corpus``).  Under the active
+    sharding rules' data mesh the job distributes, bit-identically."""
+    samples = sample_sphere(jax.random.PRNGKey(1), n_samples,
+                            d_emb.shape[-1])
+    keep, _, _ = pruning_pipeline.prune_corpus(
+        d_emb, d_mask, samples, keep_fraction, backend=backend)
+    return TokenIndex.build(d_emb, d_mask).with_keep(keep)
+
+
+def grid_rules(packed, hosts: int, *, replicas: int = 1, placement=None):
+    """The ``--mesh grid`` layout of ``packed``: capacity buckets pinned
+    to ``hosts`` host groups (``placement``, else a fresh
+    ``PlacementPlan``) on a hosts x candidates device grid.  Returns
+    ``(rules, monitor)``: the serving axis rules to enter and the
+    ``FleetMonitor`` the cross-group exchange reports to."""
+    placement = placement or shlib.PlacementPlan.for_index(
+        packed, hosts, replicas=min(replicas, hosts))
+    serve_mesh = mesh_lib.make_serve_mesh(hosts=hosts)
+    print(f"[serve] grid serving mesh: {dict(serve_mesh.shape)} "
+          f"(placement groups={list(placement.groups)}, "
+          f"replicas={placement.replicas})")
+    return (shlib.serve_rules(serve_mesh, placement=placement),
+            health.FleetMonitor(hosts))
 
 
 def _report_bytes(packed) -> None:
@@ -58,6 +147,7 @@ def _report_bytes(packed) -> None:
 
 
 def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
+                    preset: str = "smoke", n_docs: int | None = None,
                     ckpt_dir: str | None = None, seed: int = 0,
                     backend: str | None = None,
                     index_dir: str | None = None,
@@ -79,23 +169,21 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
                     serve_loop: bool = False,
                     flush_ms: float = 2.0,
                     max_batch: int = 8):
-    cfg = configs.get("colbert").smoke
-    params = colbert_lib.init_params(jax.random.PRNGKey(seed), cfg)
     if replicas < 1:
         raise ValueError(f"--replicas {replicas} < 1")
     if route != "exhaustive" and not index_dir:
         raise ValueError(f"--route {route} needs --index-dir: the routing "
                          "table is an artifact sidecar")
-    if ckpt_dir:
-        _, restored = checkpoint.restore_latest(
-            ckpt_dir, {"params": params, "opt": None, "step": None})
-        if restored is not None:
-            params = restored["params"]
-    corpus = synthetic.token_corpus(seed, n_docs=256, n_q=n_queries,
-                                    vocab=cfg.vocab, m=cfg.doc_len,
-                                    l=cfg.query_len)
+    cfg, params = load_model(preset, seed, ckpt_dir)
+    corpus = synthetic.token_corpus(
+        seed, n_docs=n_docs or DEFAULT_N_DOCS[preset], n_q=n_queries,
+        vocab=cfg.vocab, m=cfg.doc_len, l=cfg.query_len)
     if mesh == "grid" and hosts <= 0:
         hosts = mesh_lib.default_serve_hosts()
+    if mesh == "grid" and hosts <= 1:
+        raise ValueError(
+            f"--mesh grid needs >= 2 host groups; {len(jax.devices())} "
+            "device(s) form none (set --hosts, or serve without --mesh)")
     if index_dir and (upsert or delete or compact):
         # Mutation runs start by resolving any interrupted mutation a
         # previous process left behind: roll landed intents forward,
@@ -126,9 +214,7 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
             print(f"[serve] WARNING: --ckpt-dir ignored; the loaded "
                   f"artifact was encoded by the job that built it")
     else:
-        d_emb, d_mask = colbert_lib.encode_docs(params, cfg, corpus.doc_ids)
-        index = TokenIndex.build(d_emb, d_mask)
-        samples = sample_sphere(jax.random.PRNGKey(1), 2048, cfg.out_dim)
+        d_emb, d_mask = encode_corpus(params, cfg, corpus.doc_ids)
         # Length-bucketed corpus pruning: short documents run in narrow
         # shape buckets instead of paying full-doc_len padding per step.
         # Under a multi-device mesh the whole job distributes: each
@@ -140,9 +226,13 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
             data_mesh = mesh_lib.make_host_mesh()
             print(f"[serve] sharded pruning over data={data_mesh.shape['data']}")
             prune_ctx = shlib.axis_rules({"__mesh__": data_mesh})
+        print(f"[serve] pruning backend: "
+              f"{voronoi.resolve_pruning_backend(backend)}")
         with prune_ctx:
-            keep, ranks, errs = pruning_pipeline.prune_corpus(
-                d_emb, d_mask, samples, keep_fraction, backend=backend)
+            pruned = prune_index(d_emb, d_mask, keep_fraction,
+                                 n_samples=n_samples_for(preset),
+                                 backend=backend)
+        keep = pruned.keep
         if pool_threshold:
             # Token pooling (Clavié et al.): merge near-duplicate kept
             # tokens per doc before packing — the pooled corpus is what
@@ -151,13 +241,11 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
             pooled, keep = pruning_pipeline.pool_tokens(
                 np.asarray(d_emb), np.asarray(keep & d_mask),
                 pool_threshold)
-            d_emb = jnp.asarray(pooled)
-            keep = jnp.asarray(keep)
-            index = TokenIndex.build(d_emb, d_mask)
+            pruned = TokenIndex.build(jnp.asarray(pooled),
+                                      d_mask).with_keep(jnp.asarray(keep))
             after = int(np.asarray(keep).sum())
             print(f"[serve] pooled tokens at cos>={pool_threshold}: "
                   f"{before} -> {after} kept")
-        pruned = index.with_keep(keep)
         print(f"[serve] masked (reported): {pruned.storage()}")
         packed = pruned.pack(compression=compress,
                              residual_bits=residual_bits)
@@ -224,7 +312,7 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
         print(f"[serve] sharded serving mesh: {serve_mesh} "
               f"({n_shards} candidate shard{'s' if n_shards != 1 else ''})")
         ctx = shlib.axis_rules(shlib.serve_rules(serve_mesh))
-    elif mesh == "grid" and hosts > 1:
+    elif mesh == "grid":
         # --mesh grid: the multi-host placement layout.  Buckets pin to
         # host groups (PlacementPlan), each group's row of the
         # hosts x candidates mesh serves its own buckets, and only
@@ -250,18 +338,9 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
             print(f"[serve] WARNING: --replicas {replicas} ignored; the "
                   f"artifact's plan stores replicas={placement.replicas} "
                   f"(delete {index_dir} to re-place)")
-        placement = placement or shlib.PlacementPlan.for_index(
-            packed, hosts, replicas=min(replicas, hosts))
-        serve_mesh = mesh_lib.make_serve_mesh(hosts=hosts)
-        print(f"[serve] grid serving mesh: {dict(serve_mesh.shape)} "
-              f"(placement groups={list(placement.groups)}, "
-              f"replicas={placement.replicas})")
-        monitor = health.FleetMonitor(hosts)
-        ctx = shlib.axis_rules(shlib.serve_rules(serve_mesh,
-                                                 placement=placement))
-    elif mesh == "grid":
-        print("[serve] --mesh grid needs >= 2 host groups of >= 1 device; "
-              "serving unsharded (set --hosts or add devices)")
+        rules, monitor = grid_rules(packed, hosts, replicas=replicas,
+                                    placement=placement or None)
+        ctx = shlib.axis_rules(rules)
     if n_first <= 0:
         n_first = packed.n_docs                  # e2e exact-sweep route
     # Routed modes always take the streaming e2e sweep over the surviving
@@ -287,7 +366,7 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
                 monitor.demote(kill_group)
                 print(f"[serve] injected loss of host group {kill_group} "
                       f"(--on-group-loss {on_group_loss})")
-        q_emb, _ = colbert_lib.encode_queries(params, cfg, corpus.q_ids)
+        q_emb = encode_queries(params, cfg, corpus.q_ids)
         t0 = time.time()
         out = server.query_batch(q_emb)
         dt = time.time() - t0
@@ -412,7 +491,7 @@ def _mutation_lifecycle(index_dir, server, q_emb, params, cfg, seed, *,
         docs = synthetic.token_corpus(seed + 1, n_docs=upsert, n_q=1,
                                       vocab=cfg.vocab, m=cfg.doc_len,
                                       l=cfg.query_len)
-        n_emb, n_mask = colbert_lib.encode_docs(params, cfg, docs.doc_ids)
+        n_emb, n_mask = encode_corpus(params, cfg, docs.doc_ids)
         delta_id = mutation_lib.append_upsert(
             index_dir, np.asarray(n_emb), np.asarray(n_mask), new_ids)
         print(f"[serve] upserted {upsert} docs "
@@ -481,6 +560,14 @@ def serve_lm(arch: str, n_tokens: int = 32, batch: int = 2):
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
     ap.add_argument("--arch", default="colbert")
+    ap.add_argument("--preset", default="smoke", choices=list(PRESETS),
+                    help="encoder width: 'smoke' (2 layers, out_dim 32) "
+                         "or 'full' (the published ColBERT config, "
+                         "12L/768, out_dim 128, doc_len 180; random "
+                         "weights from the seed)")
+    ap.add_argument("--n-docs", type=int, default=None,
+                    help="synthetic corpus size (default: 256 for "
+                         "--preset smoke, 4096 for full)")
     ap.add_argument("--keep", type=float, default=0.5)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--tokens", type=int, default=32)
@@ -611,6 +698,12 @@ def parse_args(argv=None) -> argparse.Namespace:
                  "replicate to")
     if args.upsert < 0:
         ap.error(f"--upsert {args.upsert} must be >= 0")
+    if args.n_docs is not None and args.n_docs < 1:
+        ap.error(f"--n-docs {args.n_docs} must be >= 1")
+    if args.arch != "colbert" and (args.preset != "smoke"
+                                   or args.n_docs is not None):
+        ap.error(f"--preset/--n-docs size the retrieval corpus; --arch "
+                 f"{args.arch} decodes an LM at its smoke config")
     if args.delete is not None:
         try:
             args.delete = tuple(int(x) for x in args.delete.split(",")
@@ -670,8 +763,10 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None):
     args = parse_args(argv)
+    compile_cache.enable()
     if args.arch == "colbert":
-        serve_retrieval(keep_fraction=args.keep, ckpt_dir=args.ckpt_dir,
+        serve_retrieval(keep_fraction=args.keep, preset=args.preset,
+                        n_docs=args.n_docs, ckpt_dir=args.ckpt_dir,
                         backend=args.backend, index_dir=args.index_dir,
                         compress=args.compress,
                         residual_bits=args.residual_bits,

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro import configs
 from repro.data import pipeline, synthetic
+from repro.launch import compile_cache
 from repro.models import colbert as colbert_lib
 from repro.models import gnn as gnn_lib
 from repro.models import recsys as recsys_lib
@@ -188,6 +189,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
+    compile_cache.enable()
     out = run(args.arch, preset=args.preset, steps=args.steps,
               batch=args.batch, seq=args.seq, ckpt_dir=args.ckpt_dir,
               ckpt_every=args.ckpt_every, lr=args.lr)

@@ -65,7 +65,6 @@ def alltoall_lookup(tables: jax.Array, ids: jax.Array, *,
 
     Falls back to a plain gather when no mesh is active (CPU tests).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.specs import current_rules
@@ -127,10 +126,10 @@ def alltoall_lookup(tables: jax.Array, ids: jax.Array, *,
         return emb
 
     dp = dp_axes + shard_axes
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(P(None, shard_axes, None), P(dp, None)),
-                    out_specs=P(dp, None, None),
-                    check_rep=False)(tables, ids)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(P(None, shard_axes, None), P(dp, None)),
+                        out_specs=P(dp, None, None),
+                        check_vma=False)(tables, ids)
     return out
 
 

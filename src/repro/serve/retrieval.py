@@ -522,7 +522,6 @@ def _topk_search_sharded(index, q_embs, q_masks, k, *, backend, plan,
     single-device paths (the candidate set surviving each merge stage is
     a superset of the true top-k, and every merge uses the same
     (-score, id) total order)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     views = _index_views(index, n_shards)
@@ -573,11 +572,11 @@ def _topk_search_sharded(index, q_embs, q_masks, k, *, backend, plan,
             espec = P(ax, None, None)
         return (espec, P(ax, None), P(ax))
 
-    out = shard_map(body, mesh=mesh,
-                    in_specs=([vspec(e) for e, _, _ in views],
-                              P(None, None, None), P(None, None)),
-                    out_specs=(P(None, None), P(None, None)),
-                    check_rep=False)(views, q_embs, q_masks)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=([vspec(e) for e, _, _ in views],
+                                  P(None, None, None), P(None, None)),
+                        out_specs=(P(None, None), P(None, None)),
+                        check_vma=False)(views, q_embs, q_masks)
     return out
 
 
@@ -1856,6 +1855,19 @@ class RetrievalServer:
                 demoted, weights=bucket_weights(self.index))
             self._rebalanced_for = demoted
             return True
+
+    def lowered_text(self, q_embs: jnp.ndarray) -> str:
+        """The lowered program that answers ``q_embs``'s batch shape —
+        where a caller checks which kernels serving runs (a compiled
+        Pallas kernel appears as ``tpu_custom_call``).  Only jitted
+        routes are one program; grid and routed serving are eager
+        compositions and raise."""
+        with self._read_gate():
+            fn = self._closure_for(q_embs)
+            if not hasattr(fn, "lower"):
+                raise ValueError("this route serves an eager composition "
+                                 "of programs, not one lowered program")
+            return fn.lower(q_embs).as_text()
 
     def query_batch(self, q_embs: jnp.ndarray):
         """Serve one query batch: :class:`TopKResult` of host arrays.

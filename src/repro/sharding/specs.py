@@ -65,14 +65,11 @@ def spec_for(*logical_axes: str | None) -> P:
 def _outside_mesh_context(err: Exception) -> bool:
     """True when a ``with_sharding_constraint`` failure happened because
     no mesh context is active (the benign case ``constrain`` no-ops).
-    Checked structurally against the thread's mesh state so a JAX
-    message reword can't flip meshless hosts into raising; the error
-    text is only a fallback when the internal probe is unavailable."""
-    try:
-        from jax._src.mesh import thread_resources
-        return bool(thread_resources.env.physical_mesh.empty)
-    except Exception:
-        return "non-empty mesh" in str(err)
+    Both must hold: JAX's public context probe sees no mesh, and the
+    error is JAX's own "requires a non-empty mesh" refusal — so any
+    other error, or one raised under a mesh context, still surfaces."""
+    return (jax.sharding.get_abstract_mesh().empty
+            and "non-empty mesh" in str(err))
 
 
 def constrain(x: jax.Array, *logical_axes: str | None) -> jax.Array:

@@ -146,9 +146,10 @@ def check_prune_parity():
     bucket granularities, shortlist backend included."""
     _require_devices()
     from repro.core import pruning_pipeline, sampling
+    from repro.launch.mesh import auto_mesh
     from repro.sharding import axis_rules
 
-    mesh = jax.make_mesh((N_DEVICES, 1), ("data", "model"))
+    mesh = auto_mesh((N_DEVICES, 1), ("data", "model"))
     k = jax.random.PRNGKey(0)
     n_docs, m, dim = 13, 24, 8
     d = jax.random.normal(k, (n_docs, m, dim)) * 0.5

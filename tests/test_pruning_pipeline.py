@@ -144,6 +144,40 @@ class TestBucketedParity:
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+class TestDocBlocks:
+    """Blocked dispatch (on TPU, ``tuning.pruning_docs_per_block``):
+    buckets split into fixed-size blocks, the last padded with
+    all-masked documents — a pure execution-shape change per width."""
+
+    def test_blocks_partition_each_bucket(self, monkeypatch):
+        plan = [pp.Bucket(8, np.arange(5)), pp.Bucket(16, np.arange(5, 7))]
+        # off-TPU: one dispatch per bucket, unpadded
+        assert [rows for _, rows in pp._doc_blocks(plan, 100)] == [5, 2]
+        monkeypatch.setattr(pp, "pruning_docs_per_block", lambda n, w: 2)
+        blocks = list(pp._doc_blocks(plan, 100))
+        assert [(b.width, b.indices.tolist(), rows)
+                for b, rows in blocks] == [
+            (8, [0, 1], 2), (8, [2, 3], 2), (8, [4], 2), (16, [5, 6], 2)]
+
+    @pytest.mark.parametrize("docs_per_block", [1, 3])
+    def test_blocked_matches_whole_bucket(self, monkeypatch, docs_per_block):
+        d, masks, _ = _ragged_corpus(13, 11, 24, 8)
+        masks = masks.at[2].set(False)                    # 0 real tokens
+        S = sampling.sample_sphere(jax.random.PRNGKey(9), 300, 8)
+        whole = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
+        monkeypatch.setattr(pp, "pruning_docs_per_block",
+                            lambda n, w: docs_per_block)
+        blocked = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
+        # Orders are exact; errors only to f32 rounding: XLA vectorizes
+        # a different batch size differently.
+        np.testing.assert_array_equal(np.asarray(whole[0]),
+                                      np.asarray(blocked[0]))
+        np.testing.assert_array_equal(np.asarray(whole[2]),
+                                      np.asarray(blocked[2]))
+        np.testing.assert_allclose(np.asarray(whole[1]),
+                                   np.asarray(blocked[1]), rtol=1e-6)
+
+
 class TestPruneCorpus:
     def test_keep_masks_match_flat_global_pruning(self):
         d, masks, _ = _ragged_corpus(11, 10, 20, 8)

@@ -33,27 +33,14 @@ def _run_subprocess(code: str) -> str:
     return out.stdout
 
 
-# jax.sharding.AxisType landed after 0.4.x; Auto is the default either
-# way, so fall back to the plain make_mesh signature on older jax.
-_MAKE_MESH_COMPAT = """
-def _make_mesh(shape, names):
-    try:
-        return jax.make_mesh(shape, names,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, names)
-"""
-
-
 def test_a2a_lookup_matches_dense_fwd_and_grad():
     code = """
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.recsys import alltoall_lookup
+from repro.launch.mesh import auto_mesh
 from repro.sharding.specs import axis_rules
-""" + _MAKE_MESH_COMPAT + """
-mesh = _make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 F, V, D, B = 3, 32, 8, 16
 tables = jax.random.normal(jax.random.PRNGKey(0), (F, V, D))
 ids = jax.random.randint(jax.random.PRNGKey(1), (B, F), 0, V)
@@ -92,8 +79,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import sharding as shlib
 from repro.models import transformer as tfm
+from repro.launch.mesh import auto_mesh
 from repro.train import optimizer, train_step
-""" + _MAKE_MESH_COMPAT + """
 cfg = tfm.LMConfig(name="t", n_layers=2, d_model=32, n_heads=4,
                    n_kv_heads=2, d_ff=64, vocab=64,
                    param_dtype=jnp.float32, compute_dtype=jnp.float32,
@@ -105,7 +92,7 @@ tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
 step = train_step.lm_train_step(cfg, opt)
 _, m_ref = jax.jit(step)(state, {"tokens": tokens})
 
-mesh = _make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 rules = shlib.lm_train_rules(False)
 def fn(s, b):
     with shlib.axis_rules(rules):

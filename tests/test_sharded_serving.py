@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import make_serve_mesh
+from repro.launch.mesh import auto_mesh, make_serve_mesh
 from repro.serve.retrieval import (RetrievalServer, TokenIndex,
                                    maxsim_scores, search, topk_search)
 from repro.sharding import axis_rules, constrain, mesh_axes_for, serve_rules
@@ -280,9 +280,10 @@ class TestShardedGlobalKeepMasks:
         code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import sampling, voronoi
+from repro.launch.mesh import auto_mesh
 from repro.sharding import axis_rules
 
-mesh = jax.make_mesh((2, 1), ("data", "model"))
+mesh = auto_mesh((2, 1), ("data", "model"))
 k = jax.random.PRNGKey(0)
 n_docs, m, dim = 5, 12, 8
 d = jax.random.normal(k, (n_docs, m, dim)) * 0.5
@@ -323,7 +324,7 @@ class TestShardingPlumbing:
     def test_constrain_reraises_real_errors(self):
         """Only the outside-mesh RuntimeError is swallowed; a wrong-rank
         spec (genuine sharding bug) must surface."""
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = auto_mesh((1,), ("model",))
         with mesh:
             with axis_rules({"candidates": ("model",)}):
                 with pytest.raises(ValueError):

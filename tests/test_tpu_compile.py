@@ -1,0 +1,131 @@
+"""Compile-only checks: the main path's Pallas kernels at real widths
+compile for a TPU v5e (Mosaic), with no chip attached.
+
+Each case lowers a kernel with ``interpret=False`` against shapes placed
+on one device of a described ``v5e:2x2`` topology, compiles it with the
+installed TPU compiler, and asserts the program holds the compiled
+kernel (``tpu_custom_call``).  Widths are the ColBERT serving widths
+(dim 128, query length 32) over bucket caps m in {32, 180, 256}; block
+sizes are the ones ``core.tuning`` picks on TPU.  The Pallas
+interpreter cannot catch what these catch: lane/sublane misalignment,
+unsupported vector layouts, scoped-VMEM overflow.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.tuning import heuristic_config
+from repro.kernels.colbert_maxsim import colbert_maxsim as cm
+from repro.kernels.maxsim_top2.maxsim_top2 import maxsim_top2
+from repro.kernels.maxsim_topk.maxsim_topk import maxsim_topk
+
+DIM, L, N_Q, N_DOCS, N_SAMPLES = 128, 32, 16, 64, 1024
+CAPS = (32, 180, 256)
+CENTROIDS = 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_compiles(fn, *specs):
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _serving_block(m: int) -> int:
+    return heuristic_config("serving", platform="tpu", n_q=N_Q,
+                            n_docs=N_DOCS, m=m, l=L, dim=DIM).block_docs
+
+
+@pytest.mark.parametrize("m", CAPS)
+def test_colbert_maxsim_multi(one_chip, m):
+    bd = _serving_block(m)
+    _assert_compiles(
+        lambda q, d, mk: cm.colbert_maxsim_multi(q, d, mk, block_d=bd,
+                                                 interpret=False),
+        _spec(one_chip, (N_Q, L, DIM)), _spec(one_chip, (N_DOCS, m, DIM)),
+        _spec(one_chip, (N_DOCS, m), jnp.bool_))
+
+
+@pytest.mark.parametrize("bits", (2, 4))
+def test_colbert_maxsim_residual_multi(one_chip, bits):
+    pb = DIM * bits // 8
+    for m in CAPS:
+        bd = _serving_block(m)
+        _assert_compiles(
+            lambda q, c, r, s, cb, mk: cm.colbert_maxsim_residual_multi(
+                q, c, r, s, cb, mk, bits=bits, block_d=bd, interpret=False),
+            _spec(one_chip, (N_Q, L, DIM)),
+            _spec(one_chip, (N_DOCS, m), jnp.int8),
+            _spec(one_chip, (N_DOCS, m, pb), jnp.uint8),
+            _spec(one_chip, (N_DOCS, m, 1)),
+            _spec(one_chip, (CENTROIDS, DIM)),
+            _spec(one_chip, (N_DOCS, m), jnp.bool_))
+
+
+def test_colbert_maxsim_single_query(one_chip):
+    for m in CAPS:
+        _assert_compiles(
+            lambda q, d, mk: cm.colbert_maxsim(q, d, mk, interpret=False),
+            _spec(one_chip, (L, DIM)), _spec(one_chip, (N_DOCS, m, DIM)),
+            _spec(one_chip, (N_DOCS, m), jnp.bool_))
+
+
+def test_colbert_maxsim_residual_rerank(one_chip):
+    for bits in (2, 4):
+        pb = DIM * bits // 8
+        for m in CAPS:
+            _assert_compiles(
+                lambda q, c, r, s, cb, mk: cm.colbert_maxsim_residual_rerank(
+                    q, c, r, s, cb, mk, bits=bits, interpret=False),
+                _spec(one_chip, (L, DIM)),
+                _spec(one_chip, (N_DOCS, m), jnp.int8),
+                _spec(one_chip, (N_DOCS, m, pb), jnp.uint8),
+                _spec(one_chip, (N_DOCS, m, 1)),
+                _spec(one_chip, (N_DOCS, CENTROIDS, DIM)),
+                _spec(one_chip, (N_DOCS, m), jnp.bool_))
+
+
+@pytest.mark.parametrize("kernel", ("top2", "topk"))
+def test_pruning_kernels(one_chip, kernel):
+    for m in CAPS:
+        cfg = heuristic_config("pruning", platform="tpu",
+                               n_samples=N_SAMPLES, m=m, dim=DIM)
+        kw = dict(block_s=cfg.block_s, block_t=cfg.block_t, interpret=False)
+        if kernel == "top2":
+            fn = lambda s, t, a: maxsim_top2(s, t, a, **kw)
+        else:
+            fn = lambda s, t, a: maxsim_topk(s, t, a, k=cfg.shortlist, **kw)
+        _assert_compiles(fn, _spec(one_chip, (N_SAMPLES, DIM)),
+                         _spec(one_chip, (m, DIM)),
+                         _spec(one_chip, (m,), jnp.bool_))

@@ -62,6 +62,25 @@ class TestHeuristics:
                     + small.block_s * small.block_t) <= 256 * 1024 \
             or small.block_s == 8  # floor reached
 
+    def test_pruning_docs_per_block(self):
+        """TPU dispatches fit the HBM budget (256 docs at the full
+        10k-sample, 180-token shape); off-TPU the bucket goes whole."""
+        assert tuning.pruning_docs_per_block(10_000, 180, "tpu") == 256
+        for n, w in ((64, 8), (10_000, 180), (100_000, 512)):
+            per = tuning.pruning_docs_per_block(n, w, "tpu")
+            assert per >= 1 and per & (per - 1) == 0
+            assert per == 1 or 12 * n * w * per <= tuning.PRUNING_HBM_BUDGET
+            assert tuning.pruning_docs_per_block(n, w, "cpu") is None
+
+    def test_tpu_serving_blocks_are_sublane_tiles(self):
+        """TPU serving doc blocks are whole sublane tiles that the VMEM
+        model admits, shrinking as the bucket cap grows."""
+        blocks = [tuning.heuristic_config(
+            "serving", platform="tpu", n_q=16, n_docs=4096, m=m, l=32,
+            dim=128).block_docs for m in (32, 64, 128, 180, 256)]
+        assert all(b % 8 == 0 for b in blocks)
+        assert blocks == sorted(blocks, reverse=True)
+
     def test_unknown_kind_rejected(self):
         with pytest.raises(ValueError, match="kind"):
             tuning.heuristic_config("nope", m=8)
