@@ -12,7 +12,6 @@ Prints ``name,us_per_call,derived`` CSV per harness contract.  Modules:
   kernels — Pallas kernel micro-benches (fused vs materialized oracle)
   kernel_backends — reference vs fused/chunked hot paths; writes
             BENCH_kernel_backends.json (perf trajectory record)
-  roofline— dry-run roofline table (deliverable g summary)
 """
 
 import sys
@@ -23,7 +22,7 @@ def main() -> None:
     from benchmarks import (bench_fig1_geometry, bench_fig3_aggressive,
                             bench_fig45_positions, bench_fig6_me_ndcg,
                             bench_kernel_backends, bench_kernels,
-                            bench_roofline, bench_speedup,
+                            bench_speedup,
                             bench_table1_indomain, bench_table2_ablation,
                             bench_table3_beir)
     only = set(sys.argv[1:])
@@ -38,7 +37,6 @@ def main() -> None:
         ("fig45", bench_fig45_positions),
         ("fig6", bench_fig6_me_ndcg),
         ("speedup", bench_speedup),
-        ("roofline", bench_roofline),
     ]
     print("name,us_per_call,derived")
     failures = 0

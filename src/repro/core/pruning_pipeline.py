@@ -57,6 +57,12 @@ against the unsharded path in tests/test_placement.py).
 (bitwise-selection cut, O(log) scalar collectives — see
 voronoi._global_keep_masks_sharded), so prune -> pack -> serve is
 distributed end to end.
+
+With :mod:`repro.obs` on, :func:`prune_corpus` is marked ``repro.prune``
+(arg ``docs``) around ``repro.prune.plan`` (:func:`bucket_plan`), one
+``repro.prune.dispatch`` per dispatch block (args ``width``, ``docs``),
+``repro.prune.gather`` (the scatter back, which waits for the device)
+and ``repro.prune.merge`` (``global_keep_masks``).
 """
 
 from __future__ import annotations
@@ -67,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import backend as backend_lib
 from repro.core import voronoi
 from repro.core.tuning import _pow2_at_least, pruning_docs_per_block
@@ -203,6 +210,33 @@ def _doc_blocks(plan, n_samples: int):
             yield Bucket(bucket.width, bucket.indices[lo:lo + per]), per
 
 
+def _dispatch_block(d_embs, d_masks, samples, bucket, rows, mesh,
+                    needs_tuner, kw):
+    """Slice one block's documents to its bucket width, pad it to
+    ``rows`` documents and dispatch its pruning orders without waiting
+    for them; the pad rows are sliced off the (still pending) outputs."""
+    idx = jnp.asarray(bucket.indices)
+    e = jnp.take(d_embs, idx, axis=0)[:, :bucket.width]
+    k = jnp.take(d_masks, idx, axis=0)[:, :bucket.width]
+    pad = rows - len(bucket.indices)
+    if pad:
+        e = jnp.pad(e, ((0, pad), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, pad), (0, 0)))
+    if mesh is not None:
+        if needs_tuner:
+            # Warm the tuner for this bucket shape OUTSIDE the trace: the
+            # in-trace knob resolutions then hit the cache (measured mode
+            # must never race inside shard_map tracing).
+            backend_lib.tuned("pruning", n_samples=samples.shape[0],
+                              m=bucket.width, dim=d_embs.shape[-1])
+        out = _bucket_order_sharded(e, k, samples, mesh, **kw)
+    else:
+        out = voronoi.pruning_order_batch(e, k, samples, **kw)
+    if pad:
+        out = tuple(o[:len(bucket.indices)] for o in out)
+    return out
+
+
 def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
                            fast: bool = False, bf16_scores: bool = False,
                            shortlist: bool = False,
@@ -231,8 +265,9 @@ def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
         return jnp.asarray(ranks), jnp.asarray(errs), jnp.asarray(orders)
 
     if plan is None:
-        plan = bucket_plan(effective_lengths(d_masks), m,
-                           granularity=granularity, min_width=min_width)
+        with obs.span("repro.prune.plan"):
+            plan = bucket_plan(effective_lengths(d_masks), m,
+                               granularity=granularity, min_width=min_width)
     from repro.sharding.specs import data_mesh_for
     mesh = data_mesh_for(sharded, who="pruning_order_bucketed")
     # Only non-reference backends consume the pruning tuner's knobs —
@@ -248,33 +283,18 @@ def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
     # overlaps bucket i's compute with bucket i+1's staging — the
     # double-buffered loop), then gather.
     in_flight = []
+    kw = dict(step_size=step_size, fast=fast, bf16_scores=bf16_scores,
+              shortlist=shortlist, backend=backend)
     for bucket, rows in _doc_blocks(plan, samples.shape[0]):
-        idx = jnp.asarray(bucket.indices)
-        e = jnp.take(d_embs, idx, axis=0)[:, :bucket.width]
-        k = jnp.take(d_masks, idx, axis=0)[:, :bucket.width]
-        pad = rows - len(bucket.indices)
-        if pad:
-            e = jnp.pad(e, ((0, pad), (0, 0), (0, 0)))
-            k = jnp.pad(k, ((0, pad), (0, 0)))
-        kw = dict(step_size=step_size, fast=fast, bf16_scores=bf16_scores,
-                  shortlist=shortlist, backend=backend)
-        if mesh is not None:
-            if needs_tuner:
-                # Warm the tuner for this bucket shape OUTSIDE the
-                # trace: the in-trace knob resolutions then hit the
-                # cache (measured mode must never race inside shard_map
-                # tracing).
-                backend_lib.tuned("pruning", n_samples=samples.shape[0],
-                                  m=bucket.width, dim=d_embs.shape[-1])
-            out = _bucket_order_sharded(e, k, samples, mesh, **kw)
-        else:
-            out = voronoi.pruning_order_batch(e, k, samples, **kw)
-        if pad:
-            out = tuple(o[:len(bucket.indices)] for o in out)
+        with obs.span("repro.prune.dispatch", width=bucket.width,
+                      docs=len(bucket.indices)):
+            out = _dispatch_block(d_embs, d_masks, samples, bucket, rows,
+                                  mesh, needs_tuner, kw)
         in_flight.append((bucket, out))
-    for bucket, out in in_flight:
-        _scatter_bucket(ranks, errs, orders, bucket, out, m)
-    return jnp.asarray(ranks), jnp.asarray(errs), jnp.asarray(orders)
+    with obs.span("repro.prune.gather"):
+        for bucket, out in in_flight:
+            _scatter_bucket(ranks, errs, orders, bucket, out, m)
+        return jnp.asarray(ranks), jnp.asarray(errs), jnp.asarray(orders)
 
 
 def pool_tokens(d_embs, keep, threshold: float):
@@ -333,10 +353,12 @@ def prune_corpus(d_embs, d_masks, samples, keep_fraction: float, *,
     the per-bucket orders (:func:`pruning_order_bucketed`) and the
     global merge (``voronoi.global_keep_masks``) — with the same
     auto/force/off policy; results are bit-identical either way."""
-    ranks, errs, _ = pruning_order_bucketed(
-        d_embs, d_masks, samples, backend=backend, shortlist=shortlist,
-        step_size=step_size, granularity=granularity, min_width=min_width,
-        sharded=sharded)
-    keep = voronoi.global_keep_masks(ranks, errs, d_masks, keep_fraction,
-                                     sharded=sharded)
-    return keep, ranks, errs
+    with obs.span("repro.prune", docs=d_masks.shape[0]):
+        ranks, errs, _ = pruning_order_bucketed(
+            d_embs, d_masks, samples, backend=backend, shortlist=shortlist,
+            step_size=step_size, granularity=granularity,
+            min_width=min_width, sharded=sharded)
+        with obs.span("repro.prune.merge"):
+            keep = voronoi.global_keep_masks(ranks, errs, d_masks,
+                                             keep_fraction, sharded=sharded)
+        return keep, ranks, errs

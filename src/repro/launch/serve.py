@@ -84,7 +84,10 @@ def encode_corpus(params, cfg, doc_ids, batch: int = ENCODE_BATCH):
     one zero-padded, i.e. all-masked) -> ``(d_emb (n, m, out_dim) f32,
     d_mask (n, m) bool)``; the index stores fp32 whatever the encoder
     computes in."""
-    enc = jax.jit(lambda p, ids: colbert_lib.encode_docs(p, cfg, ids))
+    def encode_docs(p, ids):
+        return colbert_lib.encode_docs(p, cfg, ids)
+
+    enc = jax.jit(encode_docs)
     ids = np.asarray(doc_ids)
     n = ids.shape[0]
     b = max(1, min(batch, n))
@@ -102,7 +105,10 @@ def encode_corpus(params, cfg, doc_ids, batch: int = ENCODE_BATCH):
 def encode_queries(params, cfg, q_ids):
     """Encode a query batch (ColBERT [MASK] augmentation) in one jitted
     call -> ``(n_q, query_len, out_dim)`` f32."""
-    enc = jax.jit(lambda p, ids: colbert_lib.encode_queries(p, cfg, ids)[0])
+    def encode_query_batch(p, ids):
+        return colbert_lib.encode_queries(p, cfg, ids)[0]
+
+    enc = jax.jit(encode_query_batch)
     return enc(params, jnp.asarray(q_ids)).astype(jnp.float32)
 
 
@@ -456,6 +462,7 @@ def _serve_loop_leg(server, q_emb, *, flush_ms, max_batch):
     if errors:
         raise errors[0]
     snap = sl.stats.snapshot()
+    wait_ms = 1e3 * snap["queue_wait_s"] / max(snap["queries"], 1)
     pre = sum(1 for r in results if r.epoch_key == key0)
     keys = sorted({r.epoch_key for r in results})
     parity = all(
@@ -469,7 +476,10 @@ def _serve_loop_leg(server, q_emb, *, flush_ms, max_batch):
           f"batches={snap['batches']} cache_hits={snap['cache_hits']} "
           f"padded_rows={snap['padded_rows']} "
           f"p50={snap['p50_latency_s']*1e3:.2f} ms "
-          f"p99={snap['p99_latency_s']*1e3:.2f} ms")
+          f"p99={snap['p99_latency_s']*1e3:.2f} ms "
+          f"queue_wait={wait_ms:.2f} ms/query "
+          f"closure_builds={server.closure_builds} "
+          f"closure_hits={server.closure_hits}")
     print(f"[serve] loop epoch swap mid-run: {pre} answers pre-swap, "
           f"{n - pre} post-swap (epoch keys {keys})")
     print(f"[serve] loop parity vs serial: {parity}")

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.pruning_pipeline import bucket_plan
 from repro.sharding import spec_for
 from repro.train import compress
@@ -273,59 +274,65 @@ class PackedIndex:
         Lloyd's-clustered (``serve.routing.bucket_codebook``, the same
         seeded split the routing sidecar uses) and every token stores
         its nearest-centroid id plus a bit-packed ``residual_bits``-bit
-        residual under a per-token scale.
+        residual under a per-token scale.  With :mod:`repro.obs` on the
+        call is marked ``repro.pack``, and each bucket's codebook and
+        residual encode ``repro.pack.residual``.
         """
         if compression not in COMPRESSIONS:
             raise ValueError(f"compression={compression!r}; "
                              f"one of {COMPRESSIONS}")
-        embs = np.asarray(d_embs)
-        masks = np.asarray(d_masks, bool)
-        active = masks if keep is None else np.asarray(keep, bool) & masks
-        n_docs, m = active.shape
-        dim = embs.shape[-1]
-        if compression == "residual":
-            if residual_bits not in compress.RESIDUAL_BITS:
-                raise ValueError(f"residual_bits={residual_bits}; one of "
-                                 f"{compress.RESIDUAL_BITS}")
-            if dim % (8 // residual_bits):
-                raise ValueError(f"dim={dim} must be a multiple of "
-                                 f"{8 // residual_bits} for "
-                                 f"{residual_bits}-bit residuals")
-            if not 1 <= n_centroids <= 127:
-                raise ValueError("n_centroids must fit int8 codes "
-                                 f"(1..127), got {n_centroids}")
-        buckets = []
-        if n_docs:
-            plan = bucket_plan(active.sum(1), m, granularity=granularity,
-                               min_width=min_width)
-            for bi, b in enumerate(plan):
-                act = active[b.indices]
-                # stable argsort on ~mask: kept positions first, original
-                # token order preserved (MaxSim doesn't care, pooled sums do).
-                sel = np.argsort(~act, axis=1, kind="stable")[:, :b.width]
-                e = np.take_along_axis(embs[b.indices], sel[:, :, None],
-                                       axis=1)
-                mk = np.take_along_axis(act, sel, axis=1)
-                e[~mk] = 0  # deterministic bytes in the padded tail
-                bucket = PackedBucket(cap=b.width,
-                                      doc_ids=jnp.asarray(b.indices,
-                                                          jnp.int32),
-                                      masks=jnp.asarray(mk))
-                if compression == "int8":
-                    bucket.q8, bucket.scales = compress.quantize_int8(
-                        jnp.asarray(e, jnp.float32))
-                elif compression == "residual":
-                    cls._encode_residual(bucket, np.asarray(e, np.float32),
-                                         mk, residual_bits, n_centroids,
-                                         seed, bi)
-                else:
-                    bucket.embs = jnp.asarray(e)
-                buckets.append(bucket)
-        return cls(n_docs=n_docs, m=m, dim=dim,
-                   tokens_total=int(masks.sum()), compression=compression,
-                   buckets=buckets,
-                   residual_bits=(residual_bits
-                                  if compression == "residual" else 0))
+        with obs.span("repro.pack", compression=compression):
+            embs = np.asarray(d_embs)
+            masks = np.asarray(d_masks, bool)
+            active = masks if keep is None else np.asarray(keep, bool) & masks
+            n_docs, m = active.shape
+            dim = embs.shape[-1]
+            if compression == "residual":
+                if residual_bits not in compress.RESIDUAL_BITS:
+                    raise ValueError(f"residual_bits={residual_bits}; one of "
+                                     f"{compress.RESIDUAL_BITS}")
+                if dim % (8 // residual_bits):
+                    raise ValueError(f"dim={dim} must be a multiple of "
+                                     f"{8 // residual_bits} for "
+                                     f"{residual_bits}-bit residuals")
+                if not 1 <= n_centroids <= 127:
+                    raise ValueError("n_centroids must fit int8 codes "
+                                     f"(1..127), got {n_centroids}")
+            buckets = []
+            if n_docs:
+                plan = bucket_plan(active.sum(1), m, granularity=granularity,
+                                   min_width=min_width)
+                for bi, b in enumerate(plan):
+                    act = active[b.indices]
+                    # stable argsort on ~mask: kept positions first,
+                    # original token order preserved (MaxSim doesn't
+                    # care, pooled sums do).
+                    sel = np.argsort(~act, axis=1, kind="stable")[:, :b.width]
+                    e = np.take_along_axis(embs[b.indices], sel[:, :, None],
+                                           axis=1)
+                    mk = np.take_along_axis(act, sel, axis=1)
+                    e[~mk] = 0  # deterministic bytes in the padded tail
+                    bucket = PackedBucket(cap=b.width,
+                                          doc_ids=jnp.asarray(b.indices,
+                                                              jnp.int32),
+                                          masks=jnp.asarray(mk))
+                    if compression == "int8":
+                        bucket.q8, bucket.scales = compress.quantize_int8(
+                            jnp.asarray(e, jnp.float32))
+                    elif compression == "residual":
+                        with obs.span("repro.pack.residual",
+                                      cap=b.width, docs=len(b.indices)):
+                            cls._encode_residual(
+                                bucket, np.asarray(e, np.float32), mk,
+                                residual_bits, n_centroids, seed, bi)
+                    else:
+                        bucket.embs = jnp.asarray(e)
+                    buckets.append(bucket)
+            return cls(n_docs=n_docs, m=m, dim=dim,
+                       tokens_total=int(masks.sum()), compression=compression,
+                       buckets=buckets,
+                       residual_bits=(residual_bits
+                                      if compression == "residual" else 0))
 
     @staticmethod
     def _encode_residual(bucket, e, mk, bits, n_centroids, seed, bi):

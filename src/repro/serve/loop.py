@@ -36,6 +36,13 @@ into the batch shapes the stack is already optimized for:
   drains in-flight queries first — so every flush's merged batch is
   answered by exactly one epoch, and a swap lands strictly between
   flushes, never inside one.
+* **Spans and queue wait.**  With :mod:`repro.obs` on, the dispatcher
+  marks ``repro.loop.collect`` (first request in hand to the flush) and
+  ``repro.loop.flush`` around ``repro.loop.lookup`` (cache hashing),
+  ``repro.loop.batch`` (stack and pad), the server's own spans and
+  ``repro.loop.demux``; each carries the flush's ``flush`` id, and the
+  flush span its ``rows``, ``real_rows``, ``padded_rows`` and summed
+  ``queue_wait_s``.  ``LoopStats.queue_wait_s`` counts the waits always.
 
 The async dispatch half lives below this module: under a grid
 placement the monitored exchange in ``retrieval._topk_search_grid``
@@ -48,6 +55,7 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
+import itertools
 import queue
 import threading
 import time
@@ -56,6 +64,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
+from repro import obs
 from repro.core.tuning import _pow2_at_least
 from repro.serve.retrieval import TopKResult
 from repro.sharding.specs import axis_rules, current_rules
@@ -80,7 +89,11 @@ class _Request:
 
 class LoopStats:
     """Counters + latency reservoir the loop maintains under its own
-    lock; ``snapshot()`` returns a plain dict (p50/p99 in seconds)."""
+    lock; ``snapshot()`` returns a plain dict (p50/p99 in seconds).
+
+    ``queue_wait_s`` sums, over answered queries, the time from the
+    query's submit to the start of the flush that answered it, on the
+    loop's clock: ``queue_wait_s / queries`` is the mean queue wait."""
 
     def __init__(self, window: int = 4096) -> None:
         self._lock = threading.Lock()
@@ -92,6 +105,7 @@ class LoopStats:
         self.cache_hits = 0
         self.cache_misses = 0
         self.padded_rows = 0
+        self.queue_wait_s = 0.0
         self.shapes: dict = {}
 
     def record_flush(self, n_batches: int, padded: int) -> None:
@@ -104,9 +118,11 @@ class LoopStats:
         with self._lock:
             self.shapes[shape] = self.shapes.get(shape, 0) + 1
 
-    def record_query(self, latency_s: float, *, hit: bool) -> None:
+    def record_query(self, latency_s: float, *, hit: bool,
+                     wait_s: float = 0.0) -> None:
         with self._lock:
             self.queries += 1
+            self.queue_wait_s += wait_s
             if hit:
                 self.cache_hits += 1
             else:
@@ -131,6 +147,7 @@ class LoopStats:
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
                 "padded_rows": self.padded_rows,
+                "queue_wait_s": self.queue_wait_s,
                 "batch_shapes": dict(self.shapes),
                 "p50_latency_s": pct(0.50),
                 "p99_latency_s": pct(0.99),
@@ -192,6 +209,7 @@ class ServeLoop:
         # without this, a loop built inside axis_rules(serve_rules(...))
         # would silently trace unsharded closures.
         self._rules = current_rules()
+        self._flush_ids = itertools.count(1)    # dispatcher thread only
         self._thread = threading.Thread(
             target=self._run, name="serve-loop-dispatch", daemon=True)
         self._thread.start()
@@ -271,54 +289,75 @@ class ServeLoop:
             req = self._queue.get()
             if req is _SHUTDOWN:
                 return
+            flush = next(self._flush_ids)
             pending = [req]
             rows = req.n
             deadline = self._clock() + self.flush_ms / 1000.0
             stop = False
-            while rows < self.max_batch:
-                remaining = deadline - self._clock()
-                if remaining <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is _SHUTDOWN:
-                    stop = True
-                    break
-                pending.append(nxt)
-                rows += nxt.n
-            self._flush(pending)
+            with obs.span("repro.loop.collect", flush=flush) as sp:
+                while rows < self.max_batch:
+                    remaining = deadline - self._clock()
+                    if remaining <= 0:
+                        break
+                    try:
+                        nxt = self._queue.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if nxt is _SHUTDOWN:
+                        stop = True
+                        break
+                    pending.append(nxt)
+                    rows += nxt.n
+                sp.set_metadata(rows=rows)
+            with obs.span("repro.loop.flush", flush=flush) as sp:
+                self._flush(pending, flush, sp)
             if stop:
                 return
 
-    def _flush(self, pending: list) -> None:
+    def _flush(self, pending: list, flush: int, span) -> None:
         """Answer every pending request: resolve cache hits, group the
         misses by (l, dim), run one padded pow2 ``query_batch`` per
-        group, demux, cache, resolve futures."""
+        group, demux, cache, resolve futures.  Each request's queue
+        wait ends here, at the flush's start; ``span`` is the flush's
+        own, given its row counts and summed wait."""
+        t_flush = self._clock()
         # (l, dim) -> list of (request, row index in request)
         groups: dict = {}
-        epoch_key = self.server.epoch_key
-        for req in pending:
-            for i in range(req.n):
-                hit = self._cache_get(epoch_key, req.q[i])
-                if hit is not None:
-                    req.cached[i] = hit
-                else:
-                    groups.setdefault(req.q.shape[1:], []).append((req, i))
+        with obs.span("repro.loop.lookup", flush=flush):
+            epoch_key = self.server.epoch_key
+            for req in pending:
+                for i in range(req.n):
+                    hit = self._cache_get(epoch_key, req.q[i])
+                    if hit is not None:
+                        req.cached[i] = hit
+                    else:
+                        groups.setdefault(req.q.shape[1:],
+                                          []).append((req, i))
         try:
             merged = {}
             for shape, slots in sorted(groups.items(),
                                        key=lambda kv: kv[0]):
-                merged[shape] = self._run_group(shape, slots)
+                merged[shape] = self._run_group(shape, slots, flush)
         except BaseException as e:
             for req in pending:
                 if not req.future.done():
                     req.future.set_exception(e)
             return
-        self.stats.record_flush(len(groups),
-                                sum(p for _, p in merged.values()))
-        # Demux: per-request answer lists in row order.
+        padded = sum(p for _, p in merged.values())
+        self.stats.record_flush(len(groups), padded)
+        if obs.enabled():
+            span.set_metadata(
+                rows=sum(req.n for req in pending),
+                real_rows=sum(len(s) for s in groups.values()),
+                padded_rows=padded,
+                queue_wait_s=sum((t_flush - req.t_submit) * req.n
+                                 for req in pending))
+        with obs.span("repro.loop.demux", flush=flush):
+            self._demux(pending, groups, merged, t_flush)
+
+    def _demux(self, pending, groups, merged, t_flush) -> None:
+        """Slice each group's merged answer back per request (row
+        order), cache full-coverage answers, resolve the futures."""
         sliced: dict = {}
         for shape, slots in groups.items():
             out, _ = merged[shape]
@@ -338,21 +377,23 @@ class ServeLoop:
                     else per[i]
                 answers.append(res)
                 self.stats.record_query(now - req.t_submit,
-                                        hit=req.cached[i] is not None)
+                                        hit=req.cached[i] is not None,
+                                        wait_s=t_flush - req.t_submit)
             req.future.set_result(answers)
 
-    def _run_group(self, shape: tuple, slots: list):
+    def _run_group(self, shape: tuple, slots: list, flush: int):
         """One (l, dim) group's merged serve: stack the miss rows, pad
         the query axis to the autotuner's pow2 bucket (repeating the
         first row — real data, so masked/kernel paths see nothing
         unusual), run the server once, return (batch TopKResult over
         the REAL rows, padded-row count)."""
-        q = np.stack([req.q[i] for req, i in slots])
-        n_real = q.shape[0]
-        n_pad = _pow2_at_least(n_real)
-        if n_pad > n_real:
-            q = np.concatenate(
-                [q, np.broadcast_to(q[:1], (n_pad - n_real,) + shape)])
+        with obs.span("repro.loop.batch", flush=flush):
+            q = np.stack([req.q[i] for req, i in slots])
+            n_real = q.shape[0]
+            n_pad = _pow2_at_least(n_real)
+            if n_pad > n_real:
+                q = np.concatenate(
+                    [q, np.broadcast_to(q[:1], (n_pad - n_real,) + shape)])
         self.stats.record_shape((n_pad,) + shape)
         out = self.server.query_batch(q)
         res = TopKResult(np.asarray(out.top_idx[:n_real]),

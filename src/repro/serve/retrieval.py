@@ -66,6 +66,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import backend as backend_lib
 from repro.core.scoring import NEG_INF
 from repro.core.tuning import _pow2_at_least
@@ -1474,6 +1475,12 @@ class RetrievalServer:
     a re-jit on its next appearance, while the unbounded dict the server
     used to keep grew a compiled executable (plus its baked-in index
     constants) per distinct shape for the life of the process.
+    ``closure_builds`` and ``closure_hits`` count the LRU's misses and
+    hits.  With :mod:`repro.obs` on, ``query_batch`` is marked
+    ``repro.server.query_batch`` around ``repro.server.closure`` (arg
+    ``built``), ``repro.server.run`` (copy in and dispatch) and
+    ``repro.server.fetch`` (``device_get``, which waits for the device).
+    A jitted closure's program is named ``jit_serve_topk``.
 
     **Fault tolerance** (grid serving only): pass a
     ``serve.health.FleetMonitor`` as ``monitor`` and the cross-group
@@ -1552,6 +1559,8 @@ class RetrievalServer:
         # the builder's result instead of tracing twice (or holding the
         # state lock across a trace).
         self._search = collections.OrderedDict()
+        self.closure_builds = 0         # LRU misses, under _lock
+        self.closure_hits = 0
         self._placement = None          # rebalance override, grid only
         self._rebalanced_for = frozenset()
         self._mutation = None           # live MutationView, local serving
@@ -1773,29 +1782,33 @@ class RetrievalServer:
         # of a shape builds, concurrent same-shape callers park on the
         # future, and warm traffic on other shapes never queues behind
         # a compile.
-        with self._lock:
-            entry = self._search.get(key)
-            if entry is not None:
-                self._search.move_to_end(key)
-                building = False
-            else:
-                entry = concurrent.futures.Future()
-                self._search[key] = entry
-                while len(self._search) > self._max_cached:
-                    self._search.popitem(last=False)  # evict LRU shape
-                building = True
-        if not building:
-            return entry.result()
-        try:
-            fn = self._build_closure(q_embs)
-        except BaseException as e:
-            entry.set_exception(e)
+        with obs.span("repro.server.closure") as sp:
             with self._lock:
-                if self._search.get(key) is entry:
-                    del self._search[key]    # failed build: retryable
-            raise
-        entry.set_result(fn)
-        return fn
+                entry = self._search.get(key)
+                if entry is not None:
+                    self._search.move_to_end(key)
+                    building = False
+                    self.closure_hits += 1
+                else:
+                    entry = concurrent.futures.Future()
+                    self._search[key] = entry
+                    while len(self._search) > self._max_cached:
+                        self._search.popitem(last=False)  # evict LRU
+                    building = True
+                    self.closure_builds += 1
+            sp.set_metadata(built=int(building))
+            if not building:
+                return entry.result()
+            try:
+                fn = self._build_closure(q_embs)
+            except BaseException as e:
+                entry.set_exception(e)
+                with self._lock:
+                    if self._search.get(key) is entry:
+                        del self._search[key]    # failed build: retryable
+                raise
+            entry.set_result(fn)
+            return fn
 
     def _build_closure(self, q_embs):
         """Trace/compile one serving closure for the CURRENT server
@@ -1824,7 +1837,9 @@ class RetrievalServer:
             # per-group compiled programs (the cross-group candidate
             # exchange cannot live inside one jit), and routed
             # modes select their bucket shortlist host-side — both
-            # stay eager; everything else jits whole as before.
+            # stay eager; everything else jits whole as before, under
+            # a name the profiler's trace shows (jit_serve_topk).
+            fn.__name__ = "serve_topk"
             fn = jax.jit(fn)
         return fn
 
@@ -1881,9 +1896,11 @@ class RetrievalServer:
         epoch) snapshot — reported as ``result.epoch_key`` — and a
         concurrent ``swap_index``/``apply_mutation`` lands strictly
         before or strictly after it, never in the middle."""
-        with self._read_gate():
+        with obs.span("repro.server.query_batch"), self._read_gate():
             epoch_key = self.epoch_key
-            out = self._closure_for(q_embs)(q_embs)
+            fn = self._closure_for(q_embs)
+            with obs.span("repro.server.run"):
+                out = fn(q_embs)
             coverage = getattr(out, "coverage", 1.0)
             if coverage < 1.0 and self._maybe_rebalance():
                 # Answer THIS query from the rebalanced plan (new
@@ -1898,7 +1915,8 @@ class RetrievalServer:
                     f"bytes (demoted groups: {demoted}); "
                     "on_group_loss='fail' refuses degraded results")
             idx, scores = out
-            res = TopKResult(jax.device_get(idx), jax.device_get(scores),
-                             coverage)
+            with obs.span("repro.server.fetch"):
+                res = TopKResult(jax.device_get(idx), jax.device_get(scores),
+                                 coverage)
             res.epoch_key = epoch_key
             return res
