@@ -126,6 +126,30 @@ class TestPruningBackendParity:
         for a, b in zip(out_d, out_t):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    @pytest.mark.parametrize("backend", ["shortlist", "shortlist_topk"])
+    def test_batch_identical_to_reference_at_exact_ties(self, backend):
+        """Every token is duplicated and every score is a small dyadic
+        number, exact whatever the summation order, so shortlist slots
+        tie exactly in value and the kernel's scores equal the dense
+        ones.  Ranks, errors and orders then equal the reference bit for
+        bit.  Where the best slot ties, its gap is zero, so which tied
+        slot the select lands on cannot change a result; a select off
+        the argmax's slot does."""
+        rng = np.random.default_rng(3)
+        m, dim = 16, 8
+        half = rng.integers(-3, 4, (4, m // 2, dim)) / 4
+        d = np.concatenate([half, half], axis=1)[:, rng.permutation(m)]
+        masks = np.arange(m)[None, :] < np.array([m, m - 3, 7, 2])[:, None]
+        S = rng.integers(-2, 3, (512, dim)) / 2
+        d, S = jnp.asarray(d, jnp.float32), jnp.asarray(S, jnp.float32)
+        masks = jnp.asarray(masks)
+        ref = voronoi.pruning_order_batch(d, masks, S, backend="reference")
+        out = voronoi.pruning_order_batch(d, masks, S, backend=backend)
+        for a, b in zip(out, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        errs = np.asarray(ref[1])
+        assert (errs == 0).sum() >= 8, "oracle changed: too few ties"
+
     def test_no_full_m_topk_in_shortlist_topk_hlo(self):
         """Acceptance criterion: the compiled shortlist-on-maxsim_topk
         path contains no full-m lax.top_k — neither the (N, m) top_k op
@@ -158,6 +182,22 @@ class TestPruningBackendParity:
         assert not any("TopK" in ln and f"[{n},{m}]" in ln
                        for ln in topk_comp.splitlines()), \
             "shortlist_topk compiled module still calls full-m TopK"
+
+    def test_no_gather_in_shortlist_scan(self):
+        """The vmapped shortlist scan's inner step is gather-free: the
+        best index of each sample is a select over the K slots and the
+        removed token's error a min, neither a take_along_axis nor an
+        e[j] (on TPU each lowered to an element gather per step)."""
+        d, masks = _corpus(17, 3, 32, 8)
+        S = sampling.sample_sphere(jax.random.PRNGKey(12), 64, 8)
+        fn = jax.jit(jax.vmap(
+            lambda dd, kk: voronoi._pruning_order_shortlist_impl(
+                dd, kk, S, shortlist=8, rescan_every=7, bf16_scores=False,
+                rescan="dense", block_s=64, block_t=16)))
+        text = fn.lower(d, masks).as_text()
+        assert "while" in text, "oracle changed: the scan is not lowered"
+        assert '"stablehlo.gather"' not in text
+        assert "stablehlo.gather(" not in text
 
     def test_conflicting_knobs_rejected(self):
         d, mask = _doc(9, 10, 8)

@@ -129,3 +129,31 @@ def test_pruning_kernels(one_chip, kernel):
         _assert_compiles(fn, _spec(one_chip, (N_SAMPLES, DIM)),
                          _spec(one_chip, (m, DIM)),
                          _spec(one_chip, (m,), jnp.bool_))
+
+
+def test_shortlist_scan_has_no_gather(one_chip, monkeypatch):
+    """The vmapped ``shortlist_topk`` pruning scan, compiled for the chip
+    at width 128 with the tuner's K and R, holds no element gather: the
+    inner step picks each sample's best index by a select over K."""
+    from repro.core import backend as backend_lib
+    from repro.core import voronoi
+    m, docs = 128, 3
+    cfg = heuristic_config("pruning", platform="tpu", n_samples=N_SAMPLES,
+                           m=m, dim=DIM)
+    fn = jax.vmap(lambda e, k, s: voronoi._pruning_order_shortlist_impl(
+        e, k, s, shortlist=cfg.shortlist, rescan_every=cfg.rescan_every,
+        bf16_scores=False, rescan="topk", block_s=cfg.block_s,
+        block_t=cfg.block_t), in_axes=(0, 0, None))
+    # The kernel resolves compiled-vs-interpreted at trace time; trace
+    # it as on the chip, and keep that trace out of later CPU tests.
+    monkeypatch.setattr(backend_lib, "on_tpu", lambda: True)
+    jax.clear_caches()
+    try:
+        text = jax.jit(fn).lower(
+            _spec(one_chip, (docs, m, DIM)),
+            _spec(one_chip, (docs, m), jnp.bool_),
+            _spec(one_chip, (N_SAMPLES, DIM))).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in text
+    assert "gather(" not in text
