@@ -8,6 +8,7 @@ from repro.configs import (  # noqa: F401  (registration side effects)
     dlrm_rm2,
     gin_tu,
     granite_moe_3b_a800m,
+    gte_moderncolbert,
     minitron_4b,
     mixtral_8x7b,
     qwen2_5_32b,

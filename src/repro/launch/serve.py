@@ -2,7 +2,8 @@
 
   --arch colbert : end-to-end late-interaction retrieval service
                    (encode corpus -> Voronoi-prune -> pack -> batched
-                   queries).  With --index-dir the packed artifact is
+                   queries), for every arch of the "retrieval" family
+                   (colbert, gte-moderncolbert).  With --index-dir the packed artifact is
                    persisted there on first run (prune -> pack -> save ->
                    load -> serve) and loaded directly on later runs —
                    the offline-prune / online-serve split.  --upsert /
@@ -16,9 +17,10 @@
                    artifact sidecar) prunes whole capacity buckets per
                    query before any document is scored, and the run
                    reports recall@k against the exhaustive sweep.
-                   --preset full runs the published ColBERT widths
-                   (12L/768, out_dim 128, doc_len 180) with seeded
-                   random weights over --n-docs synthetic documents.
+                   --preset full runs the arch's published widths
+                   (colbert: 12L/768, out_dim 128, doc_len 180) with
+                   seeded random weights over --n-docs synthetic
+                   documents.
   --arch <lm>    : KV-cache decode loop on the smoke config
 """
 
@@ -26,6 +28,8 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
+import functools
 import threading
 import time
 
@@ -33,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import configs, obs
 from repro import sharding as shlib
 from repro.core import backend as backend_lib
 from repro.core import metrics
@@ -57,11 +61,18 @@ DEFAULT_N_DOCS = {"smoke": 256, "full": 4096}
 ENCODE_BATCH = 256
 
 
+def is_retrieval(arch: str) -> bool:
+    """Whether ``arch`` is registered in the late-interaction retrieval
+    family (served by :func:`serve_retrieval`)."""
+    return (arch in configs.all_archs()
+            and configs.get(arch).family == "retrieval")
+
+
 def load_model(preset: str = "smoke", seed: int = 0,
-               ckpt_dir: str | None = None):
-    """``(cfg, params)`` of the ColBERT encoder at ``preset`` width,
-    weights drawn from ``seed`` (or restored from ``ckpt_dir``)."""
-    entry = configs.get("colbert")
+               ckpt_dir: str | None = None, arch: str = "colbert"):
+    """``(cfg, params)`` of the retrieval encoder ``arch`` at ``preset``
+    width, weights drawn from ``seed`` (or restored from ``ckpt_dir``)."""
+    entry = configs.get(arch)
     cfg = entry.config if preset == "full" else entry.smoke
     params = colbert_lib.init_params(jax.random.PRNGKey(seed), cfg)
     if ckpt_dir:
@@ -72,31 +83,56 @@ def load_model(preset: str = "smoke", seed: int = 0,
     return cfg, params
 
 
-def n_samples_for(preset: str) -> int:
+def n_samples_for(preset: str, arch: str = "colbert") -> int:
     """Voronoi sample count: the ``prune_index`` shape at full width."""
     if preset == "full":
-        return configs.get("colbert").shapes["prune_index"].dims["n_samples"]
+        return configs.get(arch).shapes["prune_index"].dims["n_samples"]
     return 2048
 
 
-def encode_corpus(params, cfg, doc_ids, batch: int = ENCODE_BATCH):
+@dataclasses.dataclass
+class EncodeStats:
+    """What :func:`encode_corpus` computed, summed over its calls: real
+    (non-pad) tokens, and token slots (real and padded, padding rows of
+    the last batch included)."""
+
+    real_tokens: int = 0
+    slots: int = 0
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def encode_docs(params, cfg, ids):
+    return colbert_lib.encode_docs(params, cfg, ids)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def encode_query_batch(params, cfg, ids):
+    return colbert_lib.encode_queries(params, cfg, ids)[0]
+
+
+def encode_corpus(params, cfg, doc_ids, batch: int = ENCODE_BATCH,
+                  stats: EncodeStats | None = None):
     """Encode token-id documents in fixed-shape jitted batches (the last
     one zero-padded, i.e. all-masked) -> ``(d_emb (n, m, out_dim) f32,
     d_mask (n, m) bool)``; the index stores fp32 whatever the encoder
-    computes in."""
-    def encode_docs(p, ids):
-        return colbert_lib.encode_docs(p, cfg, ids)
-
-    enc = jax.jit(encode_docs)
+    computes in.  Each dispatch is the span ``repro.encode`` (args
+    ``backbone``, ``docs``, ``real_tokens``, ``slots``) and is added to
+    ``stats`` when given."""
     ids = np.asarray(doc_ids)
     n = ids.shape[0]
     b = max(1, min(batch, n))
     embs, masks = [], []
     for lo in range(0, n, b):
         chunk = ids[lo:lo + b]
+        docs, real = len(chunk), int(np.count_nonzero(chunk))
         if len(chunk) < b:
             chunk = np.pad(chunk, ((0, b - len(chunk)), (0, 0)))
-        e, mk = enc(params, jnp.asarray(chunk))
+        with obs.span("repro.encode", backbone=cfg.backbone, docs=docs,
+                      real_tokens=real, slots=chunk.size):
+            e, mk = encode_docs(params, cfg, jnp.asarray(chunk))
+        if stats is not None:
+            stats.real_tokens += real
+            stats.slots += chunk.size
         embs.append(e.astype(jnp.float32))
         masks.append(mk)
     return jnp.concatenate(embs)[:n], jnp.concatenate(masks)[:n]
@@ -105,11 +141,8 @@ def encode_corpus(params, cfg, doc_ids, batch: int = ENCODE_BATCH):
 def encode_queries(params, cfg, q_ids):
     """Encode a query batch (ColBERT [MASK] augmentation) in one jitted
     call -> ``(n_q, query_len, out_dim)`` f32."""
-    def encode_query_batch(p, ids):
-        return colbert_lib.encode_queries(p, cfg, ids)[0]
-
-    enc = jax.jit(encode_query_batch)
-    return enc(params, jnp.asarray(q_ids)).astype(jnp.float32)
+    return encode_query_batch(params, cfg,
+                              jnp.asarray(q_ids)).astype(jnp.float32)
 
 
 def prune_index(d_emb, d_mask, keep_fraction: float, *, n_samples: int,
@@ -154,6 +187,7 @@ def _report_bytes(packed) -> None:
 
 def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
                     preset: str = "smoke", n_docs: int | None = None,
+                    arch: str = "colbert",
                     ckpt_dir: str | None = None, seed: int = 0,
                     backend: str | None = None,
                     index_dir: str | None = None,
@@ -180,7 +214,7 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
     if route != "exhaustive" and not index_dir:
         raise ValueError(f"--route {route} needs --index-dir: the routing "
                          "table is an artifact sidecar")
-    cfg, params = load_model(preset, seed, ckpt_dir)
+    cfg, params = load_model(preset, seed, ckpt_dir, arch=arch)
     corpus = synthetic.token_corpus(
         seed, n_docs=n_docs or DEFAULT_N_DOCS[preset], n_q=n_queries,
         vocab=cfg.vocab, m=cfg.doc_len, l=cfg.query_len)
@@ -236,7 +270,7 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
               f"{voronoi.resolve_pruning_backend(backend)}")
         with prune_ctx:
             pruned = prune_index(d_emb, d_mask, keep_fraction,
-                                 n_samples=n_samples_for(preset),
+                                 n_samples=n_samples_for(preset, arch),
                                  backend=backend)
         keep = pruned.keep
         if pool_threshold:
@@ -569,12 +603,15 @@ def serve_lm(arch: str, n_tokens: int = 32, batch: int = 2):
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
-    ap.add_argument("--arch", default="colbert")
+    ap.add_argument("--arch", default="colbert",
+                    help="a retrieval-family arch (colbert, "
+                         "gte-moderncolbert) serves the late-interaction "
+                         "stack; any other registered arch decodes an LM")
     ap.add_argument("--preset", default="smoke", choices=list(PRESETS),
-                    help="encoder width: 'smoke' (2 layers, out_dim 32) "
-                         "or 'full' (the published ColBERT config, "
-                         "12L/768, out_dim 128, doc_len 180; random "
-                         "weights from the seed)")
+                    help="encoder width: 'smoke' (the arch's CPU-sized "
+                         "config) or 'full' (its published config, e.g. "
+                         "colbert 12L/768, out_dim 128, doc_len 180; "
+                         "random weights from the seed)")
     ap.add_argument("--n-docs", type=int, default=None,
                     help="synthetic corpus size (default: 256 for "
                          "--preset smoke, 4096 for full)")
@@ -697,6 +734,7 @@ def parse_args(argv=None) -> argparse.Namespace:
     tests/test_serve_cli.py)."""
     ap = build_parser()
     args = ap.parse_args(argv)
+    retrieval = is_retrieval(args.arch)
     if args.kill_group is not None and args.mesh != "grid":
         ap.error(f"--kill-group {args.kill_group} requires --mesh grid: "
                  "fault injection demotes a host group of the grid "
@@ -710,8 +748,8 @@ def parse_args(argv=None) -> argparse.Namespace:
         ap.error(f"--upsert {args.upsert} must be >= 0")
     if args.n_docs is not None and args.n_docs < 1:
         ap.error(f"--n-docs {args.n_docs} must be >= 1")
-    if args.arch != "colbert" and (args.preset != "smoke"
-                                   or args.n_docs is not None):
+    if not retrieval and (args.preset != "smoke"
+                          or args.n_docs is not None):
         ap.error(f"--preset/--n-docs size the retrieval corpus; --arch "
                  f"{args.arch} decodes an LM at its smoke config")
     if args.delete is not None:
@@ -758,7 +796,7 @@ def parse_args(argv=None) -> argparse.Namespace:
         if args.max_batch < 1:
             ap.error(f"--max-batch {args.max_batch} must be >= 1: a "
                      "flush serves at least one query")
-        if args.arch != "colbert":
+        if not retrieval:
             ap.error(f"--serve-loop serves the late-interaction retrieval "
                      f"stack; --arch {args.arch} decodes an LM")
     if args.route != "exhaustive" and mutating:
@@ -774,9 +812,10 @@ def parse_args(argv=None) -> argparse.Namespace:
 def main(argv=None):
     args = parse_args(argv)
     compile_cache.enable()
-    if args.arch == "colbert":
+    if is_retrieval(args.arch):
         serve_retrieval(keep_fraction=args.keep, preset=args.preset,
-                        n_docs=args.n_docs, ckpt_dir=args.ckpt_dir,
+                        n_docs=args.n_docs, arch=args.arch,
+                        ckpt_dir=args.ckpt_dir,
                         backend=args.backend, index_dir=args.index_dir,
                         compress=args.compress,
                         residual_bits=args.residual_bits,

@@ -62,12 +62,17 @@ def _project_qkv(p: AttnParams, x, n_heads, n_kv_heads, head_dim):
 
 def attention(p: AttnParams, x: jax.Array, *, n_heads: int, n_kv_heads: int,
               head_dim: int, causal: bool, window: int | None = None,
+              band: int | None = None,
               rope_theta: float | None = 1e4,
               attn_mask: jax.Array | None = None,
               positions: jax.Array | None = None,
               chunk: int | None = None,
               remat_chunk: bool = False) -> jax.Array:
     """Full-sequence attention (training / prefill). x: (B, S, D).
+
+    ``window`` is one-sided (query i sees keys j > i - window); ``band``
+    is symmetric (query i sees keys j with |i - j| <= band), the local
+    layers of a bidirectional encoder.
 
     ``chunk`` activates the blocked path: a lax.scan over query chunks so
     the live score buffer is (B, H, chunk, S) instead of (B, H, S, S) —
@@ -101,6 +106,8 @@ def attention(p: AttnParams, x: jax.Array, *, n_heads: int, n_kv_heads: int,
             vis &= jj <= ii
         if window is not None:
             vis &= jj > ii - window
+        if band is not None:
+            vis &= jnp.abs(jj - ii) <= band
         scores = jnp.where(vis[None, None, None], scores, NEG)
         if attn_mask is not None:  # (B, S) key padding mask
             scores = jnp.where(attn_mask[:, None, None, None, :], scores, NEG)
@@ -122,6 +129,8 @@ def attention(p: AttnParams, x: jax.Array, *, n_heads: int, n_kv_heads: int,
                 vis &= jj <= ii
             if window is not None:
                 vis &= jj > ii - window
+            if band is not None:
+                vis &= jnp.abs(jj - ii) <= band
             s = jnp.where(vis[None, None, None], s, NEG)
             if attn_mask is not None:
                 s = jnp.where(attn_mask[:, None, None, None, :], s, NEG)

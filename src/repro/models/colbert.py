@@ -8,8 +8,16 @@ ColBERTv2).  Two output geometries, matching §3 of the paper:
   * ``norm="ball"``   — [27]'s projection *into* the unit ball, required
     by Norm-/LP-pruning and used for the regularized fine-tuning runs.
 
+The backbone is chosen by ``ColBERTConfig.backbone``: the repo's own
+pre-norm RMSNorm + RoPE + SwiGLU block (``"rmsnorm_swiglu"``, ColBERTv2
+widths in ``configs/colbert_base.py``) or ModernBERT's
+(``"modernbert"``, GTE-ModernColBERT-v1 in
+``configs/gte_moderncolbert.py``).
+
 Queries are augmented to a fixed length with [MASK] tokens (ColBERT's
-query augmentation); documents carry padding masks.  The encoder can also
+query augmentation); documents carry padding masks.  With
+``attend_expansion=False`` (PyLate's default) the expansion tokens are
+embedded and scored but no token attends to them.  The encoder can also
 export per-token received-attention mass for the attention-score pruning
 baseline.
 """
@@ -29,6 +37,7 @@ from repro.models.common import dense_init, rms_norm
 from repro.sharding import constrain
 
 MASK_ID = 3  # reserved vocab ids: 0=pad, 1=[Q], 2=[D], 3=[MASK]
+BACKBONES = ("rmsnorm_swiglu", "modernbert")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +54,17 @@ class ColBERTConfig:
     norm: str = "sphere"            # "sphere" | "ball"
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
+    backbone: str = "rmsnorm_swiglu"    # | "modernbert"
+    global_every: int = 0           # modernbert: every n-th layer is global
+    local_window: int = 0           # modernbert: local layers' window
+    rope_theta: float = 1e4         # RoPE base (modernbert: global layers)
+    local_rope_theta: float = 1e4   # modernbert: RoPE base of local layers
+    attend_expansion: bool = True   # queries attend to [MASK] expansion
+
+    def __post_init__(self):
+        if self.backbone not in BACKBONES:
+            raise ValueError(f"backbone {self.backbone!r} not in "
+                             f"{BACKBONES}")
 
     def lm_config(self) -> tfm.LMConfig:
         return tfm.LMConfig(
@@ -53,7 +73,10 @@ class ColBERTConfig:
             n_kv_heads=self.n_heads, d_ff=self.d_ff, vocab=self.vocab,
             causal=False, tie_embeddings=True,
             param_dtype=self.param_dtype, compute_dtype=self.compute_dtype,
-            remat=False)
+            remat=False, block=self.backbone,
+            global_every=self.global_every, local_window=self.local_window,
+            rope_theta=self.rope_theta,
+            local_rope_theta=self.local_rope_theta)
 
     def param_count(self) -> int:
         return self.lm_config().param_count() + self.d_model * self.out_dim
@@ -84,17 +107,21 @@ def encode(params, cfg: ColBERTConfig, token_ids, attn_mask):
 
 
 def encode_queries(params, cfg: ColBERTConfig, token_ids):
-    """Query augmentation: pad/truncate to query_len with [MASK]; all
-    positions attend (masks participate in scoring, per ColBERT)."""
+    """Query augmentation: pad/truncate to query_len with [MASK]; every
+    position is scored (masks participate in scoring, per ColBERT), and
+    the expansion tokens are attended to unless ``attend_expansion`` is
+    off."""
     B, S = token_ids.shape
     if S < cfg.query_len:
         pad = jnp.full((B, cfg.query_len - S), MASK_ID, token_ids.dtype)
         token_ids = jnp.concatenate([token_ids, pad], axis=1)
     else:
         token_ids = token_ids[:, :cfg.query_len]
+    real = (token_ids != 0) & (token_ids != MASK_ID)
     token_ids = jnp.where(token_ids == 0, MASK_ID, token_ids)
     mask = jnp.ones_like(token_ids, dtype=bool)
-    return encode(params, cfg, token_ids, mask), mask
+    attend = mask if cfg.attend_expansion else real
+    return encode(params, cfg, token_ids, attend), mask
 
 
 def encode_docs(params, cfg: ColBERTConfig, token_ids):

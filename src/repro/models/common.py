@@ -26,13 +26,17 @@ def rms_norm(x, gamma, eps=1e-6):
     return (y * gamma.astype(jnp.float32)).astype(dt)
 
 
-def layer_norm(x, gamma, beta, eps=1e-6):
+def layer_norm(x, gamma, beta=None, eps=1e-6):
+    """LayerNorm over the last axis, in fp32; ``beta=None`` is the
+    bias-free form."""
     dt = x.dtype
     x = x.astype(jnp.float32)
     mu = x.mean(-1, keepdims=True)
     var = jnp.mean((x - mu) ** 2, axis=-1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + eps)
-    return (y * gamma.astype(jnp.float32) + beta.astype(jnp.float32)).astype(dt)
+    y = (x - mu) * jax.lax.rsqrt(var + eps) * gamma.astype(jnp.float32)
+    if beta is not None:
+        y = y + beta.astype(jnp.float32)
+    return y.astype(dt)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float = 1e4) -> jax.Array:
@@ -50,8 +54,13 @@ def rope(x: jax.Array, positions: jax.Array, theta: float = 1e4) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def gelu(x):
-    return jax.nn.gelu(x)
+def geglu(x, w_in, w_out):
+    """GeGLU feed-forward: ``[a, g] = split(x @ w_in)``, then
+    ``(GELU(a) * g) @ w_out``, with the exact (erf) GELU."""
+    a, g = jnp.split(x @ w_in, 2, axis=-1)
+    h = jax.nn.gelu(a, approximate=False) * g
+    h = constrain(h, "batch", "seq", "ffn")
+    return h @ w_out
 
 
 def swiglu(x, w_gate, w_up, w_down, b_gate=None, b_up=None, b_down=None):

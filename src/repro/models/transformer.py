@@ -13,6 +13,16 @@ Design points for the multi-pod posture:
 
 Modes: causal LM (train/prefill/decode) and bidirectional encoder
 (ColBERT / BERT4Rec backbones).
+
+Two encoder blocks: the default pre-norm RMSNorm + RoPE + SwiGLU block,
+and ModernBERT's (``block="modernbert"``, Warner et al., arXiv:2412.13663):
+bias-free LayerNorm (eps 1e-5), a normed embedding and no attention norm
+in layer 0, a GeGLU FFN, and layer i global (full attention, RoPE base
+``rope_theta``) when ``i % global_every == 0``, else local (``|i - j| <=
+local_window // 2``, RoPE base ``local_rope_theta``).  Its layer 0 runs
+unrolled and the rest as a scan over stacked periods of ``global_every``
+layers (locals, then the global), so the HLO stays O(1) in depth and every
+mask is static.
 """
 
 from __future__ import annotations
@@ -25,7 +35,8 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
-from repro.models.common import dense_init, embed_init, rms_norm, swiglu
+from repro.models.common import (dense_init, embed_init, geglu, layer_norm,
+                                 rms_norm, swiglu)
 from repro.sharding import constrain
 
 
@@ -53,6 +64,10 @@ class LMConfig:
     param_dtype: Any = jnp.bfloat16
     compute_dtype: Any = jnp.bfloat16
     remat: bool = True
+    block: str = "rmsnorm_swiglu"      # | "modernbert" (encoder only)
+    global_every: int = 0              # modernbert: global iff i % this == 0
+    local_window: int = 0              # modernbert local: |i - j| <= this // 2
+    local_rope_theta: float = 1e4      # modernbert: RoPE base of local layers
 
     @property
     def hd(self) -> int:
@@ -66,6 +81,8 @@ class LMConfig:
             ffn = self.moe_experts * 3 * d * f + d * self.moe_experts
         else:
             ffn = 3 * d * f
+        # ModernBERT's embedding norm stands in for layer 0's attention
+        # norm, so the count is the same for both blocks.
         per_layer = attn + ffn + 2 * d
         emb = v * d * (1 if self.tie_embeddings else 2)
         return self.n_layers * per_layer + emb + d
@@ -91,6 +108,12 @@ def init_layer(key, cfg: LMConfig):
         p["moe"] = moe_lib.init_moe(kf, cfg.d_model, cfg.d_ff,
                                     cfg.moe_experts,
                                     cfg.param_dtype)._asdict()
+    elif cfg.block == "modernbert":
+        k1, k2 = jax.random.split(kf)
+        p["ffn"] = {
+            "wi": dense_init(k1, cfg.d_model, 2 * cfg.d_ff, cfg.param_dtype),
+            "wo": dense_init(k2, cfg.d_ff, cfg.d_model, cfg.param_dtype),
+        }
     else:
         k1, k2, k3 = jax.random.split(kf, 3)
         p["ffn"] = {
@@ -108,6 +131,8 @@ def init_attn_params(key, cfg: LMConfig):
 
 
 def init_params(key, cfg: LMConfig):
+    if cfg.block == "modernbert":
+        return _init_modernbert(key, cfg)
     ke, kl, kh = jax.random.split(key, 3)
     layer_keys = jax.random.split(kl, cfg.n_layers)
     layers = jax.vmap(lambda k: init_layer(k, cfg))(layer_keys)
@@ -174,6 +199,8 @@ def forward(params, tokens, cfg: LMConfig, *, attn_mask=None,
 
 def hidden_states(params, tokens, cfg: LMConfig, *, attn_mask=None):
     """Final-layer hidden states (encoder mode for retrieval backbones)."""
+    if cfg.block == "modernbert":
+        return _modernbert_hidden_states(params, tokens, cfg, attn_mask)
     x = params["embed"][tokens].astype(cfg.compute_dtype)
     x = constrain(x, "batch", "seq", "embed")
 
@@ -183,6 +210,82 @@ def hidden_states(params, tokens, cfg: LMConfig, *, attn_mask=None):
     blk = jax.checkpoint(body, prevent_cse=False) if cfg.remat else body
     x, _ = jax.lax.scan(blk, x, params["layers"])
     return rms_norm(x, params["ln_f"])
+
+
+# ------------------------ ModernBERT encoder -------------------------------
+
+MODERNBERT_NORM_EPS = 1e-5
+
+
+def _modernbert_periods(cfg: LMConfig) -> int:
+    """Stacked periods after the unrolled layer 0."""
+    g = cfg.global_every
+    if cfg.causal or g < 1 or (cfg.n_layers - 1) % g:
+        raise ValueError(
+            f"{cfg.name}: the modernbert block is a bidirectional encoder "
+            f"of 1 + k * global_every layers; got causal={cfg.causal}, "
+            f"n_layers={cfg.n_layers}, global_every={g}")
+    return (cfg.n_layers - 1) // g
+
+
+def _init_modernbert(key, cfg: LMConfig):
+    """Layer 0 on its own, without an attention norm (``ln1``); layers
+    1.. stacked as (periods, global_every, ...)."""
+    n_periods = _modernbert_periods(cfg)
+    ke, kl = jax.random.split(key)
+    keys = jax.random.split(kl, cfg.n_layers)
+    layer0 = init_layer(keys[0], cfg)
+    del layer0["ln1"]
+    rest = jax.vmap(lambda k: init_layer(k, cfg))(keys[1:])
+    rest = jax.tree_util.tree_map(
+        lambda a: a.reshape((n_periods, cfg.global_every) + a.shape[1:]),
+        rest)
+    return {
+        "embed": embed_init(ke, cfg.vocab, cfg.d_model, cfg.param_dtype),
+        "embed_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
+        "layer0": layer0,
+        "layers": rest,
+        "ln_f": jnp.ones((cfg.d_model,), cfg.param_dtype),
+    }
+
+
+def _modernbert_block(cfg: LMConfig, x, layer, attn_mask, *, local: bool):
+    """One ModernBERT layer; a layer without ``ln1`` (layer 0) feeds the
+    attention its input as it is."""
+    ap = attn_lib.AttnParams(**layer["attn"])
+    h = x
+    if "ln1" in layer:
+        h = layer_norm(x, layer["ln1"], eps=MODERNBERT_NORM_EPS)
+    h = attn_lib.attention(
+        ap, h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.hd, causal=False,
+        band=cfg.local_window // 2 if local else None,
+        rope_theta=cfg.local_rope_theta if local else cfg.rope_theta,
+        attn_mask=attn_mask)
+    x = constrain(x + h, "batch", "seq", "embed")
+    h = layer_norm(x, layer["ln2"], eps=MODERNBERT_NORM_EPS)
+    h = geglu(h, layer["ffn"]["wi"], layer["ffn"]["wo"])
+    return constrain(x + h, "batch", "seq", "embed")
+
+
+def _modernbert_hidden_states(params, tokens, cfg: LMConfig, attn_mask):
+    g = cfg.global_every
+    _modernbert_periods(cfg)
+    x = params["embed"][tokens].astype(cfg.compute_dtype)
+    x = layer_norm(x, params["embed_norm"], eps=MODERNBERT_NORM_EPS)
+    x = constrain(x, "batch", "seq", "embed")
+    x = _modernbert_block(cfg, x, params["layer0"], attn_mask, local=False)
+
+    def period(carry, layers):
+        for k in range(g):
+            layer = jax.tree_util.tree_map(lambda a: a[k], layers)
+            carry = _modernbert_block(cfg, carry, layer, attn_mask,
+                                      local=k < g - 1)
+        return carry, None
+
+    blk = jax.checkpoint(period, prevent_cse=False) if cfg.remat else period
+    x, _ = jax.lax.scan(blk, x, params["layers"])
+    return layer_norm(x, params["ln_f"], eps=MODERNBERT_NORM_EPS)
 
 
 # --------------------------- decode path ----------------------------------

@@ -131,6 +131,19 @@ def test_pruning_kernels(one_chip, kernel):
                          _spec(one_chip, (m,), jnp.bool_))
 
 
+def test_pruning_kernel_at_document_length_300(one_chip):
+    """GTE-ModernColBERT's 300-token documents prune in a bucket 300 wide,
+    which is not a multiple of the kernel's token block."""
+    cfg = heuristic_config("pruning", platform="tpu", n_samples=N_SAMPLES,
+                           m=300, dim=DIM)
+    _assert_compiles(
+        lambda s, t, a: maxsim_topk(s, t, a, k=cfg.shortlist,
+                                    block_s=cfg.block_s, block_t=cfg.block_t,
+                                    interpret=False),
+        _spec(one_chip, (N_SAMPLES, DIM)), _spec(one_chip, (300, DIM)),
+        _spec(one_chip, (300,), jnp.bool_))
+
+
 def test_shortlist_scan_has_no_gather(one_chip, monkeypatch):
     """The vmapped ``shortlist_topk`` pruning scan, compiled for the chip
     at width 128 with the tuner's K and R, holds no element gather: the
