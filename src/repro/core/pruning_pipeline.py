@@ -62,7 +62,8 @@ With :mod:`repro.obs` on, :func:`prune_corpus` is marked ``repro.prune``
 (arg ``docs``) around ``repro.prune.plan`` (:func:`bucket_plan`), one
 ``repro.prune.dispatch`` per dispatch block (args ``width``, ``docs``),
 ``repro.prune.gather`` (the scatter back, which waits for the device)
-and ``repro.prune.merge`` (``global_keep_masks``).
+and ``repro.prune.merge`` (``global_keep_masks``; arg ``traced``, 1 when
+the call traced a new merge program, see ``voronoi.merge_traces``).
 """
 
 from __future__ import annotations
@@ -358,7 +359,9 @@ def prune_corpus(d_embs, d_masks, samples, keep_fraction: float, *,
             d_embs, d_masks, samples, backend=backend, shortlist=shortlist,
             step_size=step_size, granularity=granularity,
             min_width=min_width, sharded=sharded)
-        with obs.span("repro.prune.merge"):
+        with obs.span("repro.prune.merge") as sp:
+            traces = voronoi.merge_traces()
             keep = voronoi.global_keep_masks(ranks, errs, d_masks,
                                              keep_fraction, sharded=sharded)
+            sp.set_metadata(traced=int(voronoi.merge_traces() > traces))
         return keep, ranks, errs

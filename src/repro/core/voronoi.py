@@ -30,6 +30,7 @@ Shape conventions: one document is (m, dim) + bool mask (m,); samples
 from __future__ import annotations
 
 import functools
+import threading
 from typing import NamedTuple
 
 import jax
@@ -52,6 +53,7 @@ __all__ = [
     "keep_mask_from_order",
     "prune_to_size",
     "global_keep_masks",
+    "merge_traces",
     "mean_error",
     "mean_error_batch",
 ]
@@ -719,6 +721,41 @@ def _global_keep_masks_sharded(ranks, errs, d_masks, keep_fraction, *,
     return keep[:n_docs]
 
 
+_merge_lock = threading.Lock()
+_merge_traces = 0
+
+
+def merge_traces() -> int:
+    """How many times this process has traced the single-device §4.2
+    merge program (:func:`_global_keep_masks_local`): once per
+    (n_docs, m, dtypes, keep_fraction) it has met.  A build loop over
+    fixed-shape slabs reads 1 after its first slab and stays there."""
+    return _merge_traces
+
+
+@functools.partial(jax.jit, static_argnames=("keep_fraction",))
+def _global_keep_masks_local(ranks, errs, d_masks, keep_fraction):
+    """The unsharded §4.2 merge as one compiled program: monotonized
+    merge keys, the corpus budget, and a stable argsort cut.
+    ``keep_fraction`` is static, a Python float, so
+    ``ceil(keep_fraction * n_total)`` is a weak-typed f32 product."""
+    global _merge_traces
+    with _merge_lock:
+        _merge_traces += 1          # the body runs only while tracing
+    n_docs, m = ranks.shape
+    mono_err = _monotone_merge_errs(ranks, errs, d_masks)
+    n_total = jnp.sum(d_masks)
+    n_keep = jnp.ceil(keep_fraction * n_total).astype(jnp.int32)
+    n_prune = jnp.maximum(n_total - n_keep, 0)
+    flat = mono_err.reshape(-1)
+    # Prune the n_prune smallest keys; the stable sort breaks ties in flat
+    # order, so the budget is met exactly.
+    sort_ix = jnp.argsort(flat)
+    cut = jnp.arange(flat.shape[0]) < n_prune
+    pruned_flat = jnp.zeros_like(flat, bool).at[sort_ix].set(cut)
+    return d_masks & ~pruned_flat.reshape(n_docs, m)
+
+
 def global_keep_masks(ranks: jax.Array, errs: jax.Array, d_masks: jax.Array,
                       keep_fraction: float, *,
                       sharded: bool | None = None) -> jax.Array:
@@ -731,6 +768,9 @@ def global_keep_masks(ranks: jax.Array, errs: jax.Array, d_masks: jax.Array,
     merge (a later-removed token never merges before an earlier one).
     Every document always retains >= 1 token (err inf on the survivor).
 
+    Unsharded, the merge is one jitted program
+    (:func:`_global_keep_masks_local`), compiled once per shape and
+    ``keep_fraction`` (:func:`merge_traces` counts its traces).
     ``sharded`` selects the distributed merge
     (:func:`_global_keep_masks_sharded`): the per-doc monotonization
     shards over the ``data`` mesh axis and the global cut runs as a
@@ -748,19 +788,8 @@ def global_keep_masks(ranks: jax.Array, errs: jax.Array, d_masks: jax.Array,
         return _global_keep_masks_sharded(ranks, errs, d_masks,
                                           keep_fraction, mesh=mesh,
                                           axis="data")
-    n_docs, m = ranks.shape
-    mono_err = _monotone_merge_errs(ranks, errs, d_masks)
-    n_total = jnp.sum(d_masks)
-    n_keep = jnp.ceil(keep_fraction * n_total).astype(jnp.int32)
-    n_prune = jnp.maximum(n_total - n_keep, 0)
-    flat = mono_err.reshape(-1)
-    # Threshold = n_prune-th smallest finite error; prune strictly below,
-    # then break ties by rank to hit the budget exactly.
-    sort_ix = jnp.argsort(flat)
-    cut = jnp.where(jnp.arange(flat.shape[0]) < n_prune, True, False)
-    pruned_flat = jnp.zeros_like(flat, bool).at[sort_ix].set(cut)
-    keep = d_masks & ~pruned_flat.reshape(n_docs, m)
-    return keep
+    return _global_keep_masks_local(ranks, errs, d_masks,
+                                    keep_fraction=float(keep_fraction))
 
 
 def mean_error(d_emb: jax.Array, d_mask: jax.Array, keep_mask: jax.Array,

@@ -7,7 +7,8 @@ in a TPU's.  Covered: the off path builds nothing and records nothing;
 spans nest and carry their arguments; one flush's span tree with its
 shared flush id and row counts equal to LoopStats'; the queue wait under
 an injected clock; the closure LRU's build and hit counters; one pruning
-dispatch span per dispatch block; the serving program's name.
+dispatch span per dispatch block; the merge program traced once per shape
+and keep fraction; the serving program's name.
 """
 
 import glob
@@ -22,7 +23,7 @@ import pytest
 
 from repro import obs
 from repro.core import pruning_pipeline as pp
-from repro.core import sampling
+from repro.core import sampling, voronoi
 from repro.serve.index import PackedIndex
 from repro.serve.loop import ServeLoop
 from repro.serve.retrieval import RetrievalServer, TokenIndex
@@ -272,6 +273,28 @@ def test_prune_corpus_marks_each_dispatch_block(spans_on, tmp_path,
     assert steps == (["repro.prune.plan"]
                      + ["repro.prune.dispatch"] * len(blocks)
                      + ["repro.prune.gather", "repro.prune.merge"])
+
+
+def test_merge_traces_once_per_shape_and_keep_fraction(spans_on, tmp_path):
+    """Same-shape slabs reuse one compiled §4.2 merge; a new width or a
+    new keep fraction traces it once more, and ``repro.prune.merge``
+    says which calls traced."""
+    samples = sampling.sample_sphere(jax.random.PRNGKey(2), 64, 4)
+    voronoi._global_keep_masks_local.clear_cache()  # so the first call traces
+    slab = [_packed(seed=s, n_docs=13, m=24, dim=4)[:2] for s in (5, 6)]
+    wide = _packed(seed=7, n_docs=13, m=40, dim=4)[:2]
+    calls = [(*slab[0], 0.5), (*slab[1], 0.5), (*wide, 0.5),
+             (*slab[1], 0.25), (*slab[0], 0.25)]
+    before = voronoi.merge_traces()
+
+    def work():
+        for d, masks, frac in calls:
+            np.asarray(pp.prune_corpus(d, masks, samples, frac)[0])
+    ev = _traced(tmp_path, work)
+    merges = [e for e in ev if e["name"] == "repro.prune.merge"]
+    assert [e["args"] for e in merges] == [
+        {"traced": t} for t in (1, 0, 1, 1, 0)]
+    assert voronoi.merge_traces() - before == 3
 
 
 def test_pack_marks_the_residual_encode(spans_on, tmp_path):

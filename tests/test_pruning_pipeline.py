@@ -194,6 +194,69 @@ class TestPruneCorpus:
             assert (per_doc[np.asarray(masks.sum(1)) > 0] >= 1).all()
 
 
+def _merge_inputs(seed, n_docs, m, *, tie_levels=0, n_masked=0):
+    """Per-document removal orders as the pruning scan emits them: the
+    first ``n_real - 1`` real tokens of a random permutation are removed
+    at ranks 0.. with nonnegative errors, the last survives (rank m, err
+    inf), dead slots carry rank m and err inf.  ``tie_levels`` draws
+    errors from that many values, so keys tie within and across
+    documents; the first ``n_masked`` documents are fully masked."""
+    rng = np.random.default_rng(seed)
+    n_real = rng.integers(1, m + 1, n_docs)
+    n_real[:n_masked] = 0
+    masks = np.arange(m)[None, :] < n_real[:, None]
+    ranks = np.full((n_docs, m), m, np.int32)
+    errs = np.full((n_docs, m), np.inf, np.float32)
+    for i, k in enumerate(n_real):
+        gone = rng.permutation(k)[:max(k - 1, 0)]
+        ranks[i, gone] = np.arange(gone.size)
+        errs[i, gone] = (rng.integers(0, tie_levels, gone.size) / 4
+                         if tie_levels else rng.random(gone.size))
+    return ranks, errs, masks
+
+
+def _merge_reference(ranks, errs, masks, keep_fraction):
+    """§4.2 in plain numpy: each document's errors made monotone along
+    its own removal order (running max), then the smallest
+    ``n_total - ceil(keep_fraction * n_total)`` keys of the whole corpus
+    pruned, ties in flat order (a stable sort)."""
+    keys = np.full(masks.shape, np.inf, np.float32)
+    for d in range(masks.shape[0]):
+        gone = np.flatnonzero(masks[d] & np.isfinite(errs[d]))
+        order = gone[np.argsort(ranks[d, gone], kind="stable")]
+        keys[d, order] = np.maximum.accumulate(errs[d, order])
+    n_total = int(masks.sum())
+    n_prune = max(n_total - int(np.ceil(keep_fraction * n_total)), 0)
+    pruned = np.zeros(keys.size, bool)
+    pruned[np.argsort(keys.reshape(-1), kind="stable")[:n_prune]] = True
+    return masks & ~pruned.reshape(masks.shape)
+
+
+MERGE_CASES = {
+    "ragged-180": dict(n_docs=64, m=180),
+    "ragged-300": dict(n_docs=64, m=300),
+    "tied-errors": dict(n_docs=64, m=180, tie_levels=3),
+    "masked-docs": dict(n_docs=64, m=300, n_masked=5, tie_levels=2),
+}
+
+
+class TestGlobalMerge:
+    """The compiled §4.2 merge (``voronoi.global_keep_masks``) against a
+    plain numpy merge, bit for bit, at the build cells' slab shapes."""
+
+    @pytest.mark.parametrize("keep_fraction", [0.0, 0.5, 1.0])
+    @pytest.mark.parametrize("case", sorted(MERGE_CASES))
+    def test_matches_numpy_reference(self, case, keep_fraction):
+        ranks, errs, masks = _merge_inputs(sorted(MERGE_CASES).index(case),
+                                           **MERGE_CASES[case])
+        keep = voronoi.global_keep_masks(jnp.asarray(ranks),
+                                         jnp.asarray(errs),
+                                         jnp.asarray(masks), keep_fraction)
+        np.testing.assert_array_equal(
+            np.asarray(keep), _merge_reference(ranks, errs, masks,
+                                               keep_fraction))
+
+
 class TestPoolTokens:
     """Within-document token pooling (the pre-pack merge pass the
     residual codec composes with)."""

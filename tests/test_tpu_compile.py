@@ -4,7 +4,8 @@ compile for a TPU v5e (Mosaic), with no chip attached.
 Each case lowers a kernel with ``interpret=False`` against shapes placed
 on one device of a described ``v5e:2x2`` topology, compiles it with the
 installed TPU compiler, and asserts the program holds the compiled
-kernel (``tpu_custom_call``).  Widths are the ColBERT serving widths
+kernel (``tpu_custom_call``); the §4.2 merge, which has no kernel,
+compiles as one program.  Widths are the ColBERT serving widths
 (dim 128, query length 32) over bucket caps m in {32, 180, 256}; block
 sizes are the ones ``core.tuning`` picks on TPU.  The Pallas
 interpreter cannot catch what these catch: lane/sublane misalignment,
@@ -142,6 +143,19 @@ def test_pruning_kernel_at_document_length_300(one_chip):
                                     interpret=False),
         _spec(one_chip, (N_SAMPLES, DIM)), _spec(one_chip, (300, DIM)),
         _spec(one_chip, (300,), jnp.bool_))
+
+
+@pytest.mark.parametrize("n_docs,m", [(64, 180), (64, 300), (8192, 180)])
+def test_global_merge_compiles(one_chip, n_docs, m):
+    """The §4.2 merge compiles for the chip as one program at a build
+    slab of each document length and at a serving index's corpus."""
+    from repro.core import voronoi
+    compiled = voronoi._global_keep_masks_local.lower(
+        _spec(one_chip, (n_docs, m), jnp.int32),
+        _spec(one_chip, (n_docs, m)),
+        _spec(one_chip, (n_docs, m), jnp.bool_),
+        keep_fraction=0.5).compile()
+    assert "sort(" in compiled.as_text()
 
 
 def test_shortlist_scan_has_no_gather(one_chip, monkeypatch):
