@@ -1,8 +1,8 @@
-"""Backend-dispatch perf record: reference vs fused/shortlist hot paths.
+"""Backend-dispatch perf record: reference vs kernel hot paths.
 
 Measures the two hot paths the dispatch seam (repro.core.backend)
-routes — iterative Voronoi pruning (all four backends + the bucketed
-corpus pipeline + the ragged-corpus comparison) and MaxSim serving —
+routes — iterative Voronoi pruning (both paths + the bucketed corpus
+pipeline + the ragged-corpus comparison) and MaxSim serving —
 plus the packed-vs-masked index-layout comparison (same pruned corpus
 served from the dense masked `TokenIndex` and from the compacted
 `PackedIndex`, throughput AND measured bytes) and the serving-dataflow
@@ -18,14 +18,14 @@ Shapes are CPU-scaled but chosen so the *serving* comparison is
 meaningful off-TPU too: at the rerank shape the reference einsum's 4-D
 (n_q, n_docs, l, m) tensor exceeds LLC and the chunked kernel path wins
 outright even through the Pallas interpreter.  The pruning comparison
-off-TPU prices the interpreter per scan step for the fused/topk paths,
-so those docs/sec are lower bounds (the TPU numbers are the ones that
-matter); the reference, dense-shortlist and bucketed figures are real
-either way.
+off-TPU prices the interpreter per scan step for the shortlist_topk
+path, so its docs/sec is a lower bound (the TPU numbers are the ones
+that matter); the reference and bucketed figures are real either way.
 
 ``python -m benchmarks.bench_kernel_backends --check`` re-reads the
-last trajectory entry and fails (exit 1) if batched pruning regressed
-below the same run's reference-path docs/sec, if packed serving
+last trajectory entry and fails (exit 1) if the bucketed pruning
+pipeline on the platform's path regressed below the same run's
+reference-path docs/sec, if packed serving
 dropped below the masked path, if streaming serving dropped below the
 materializing path (or its results diverged), if a corpus-sized
 (n_q, n_docs) score tensor reappeared in the compiled streaming
@@ -65,7 +65,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
-from benchmarks.bench_speedup import run_pruning_backends, run_ragged_pruning
+from benchmarks.bench_speedup import (PRUNING_BACKENDS, run_pruning_backends,
+                                      run_ragged_pruning)
 from repro.serve.retrieval import (TokenIndex, maxsim_scores, search,
                                    topk_search)
 
@@ -76,9 +77,6 @@ OUT_PATH = os.path.abspath(os.path.join(os.path.dirname(__file__),
 # Rerank benchmark shape: 4-D reference tensor = 32*256*32*128 f32
 # = 134 MB — large enough that materializing it is the bottleneck.
 RERANK = dict(n_q=32, n_docs=256, m=128, l=32, dim=128, block_docs=64)
-
-PRUNING_BACKENDS = ("reference", "fused", "shortlist", "shortlist_topk",
-                    "bucketed_shortlist")
 
 
 def run_rerank_backends(n_q=32, n_docs=256, m=128, l=32, dim=128,
@@ -915,21 +913,23 @@ def append_entry(entry: dict, path: str = OUT_PATH) -> None:
 
 
 def check_last(path: str = OUT_PATH) -> None:
-    """Throughput smoke: batched corpus pruning (bucketed shortlist)
-    must not regress below the same entry's reference-path docs/sec."""
+    """Throughput smoke: batched corpus pruning (the bucketed pipeline
+    on the platform's path) must not regress below the same entry's
+    reference-path docs/sec."""
     entries = load_trajectory(path)
     if not entries:
         raise SystemExit(f"{path}: no trajectory entries; run the bench")
     last = entries[-1]
     docs = last.get("pruning_docs_per_s", {})
-    bucketed = docs.get("bucketed_shortlist")
+    bucketed = docs.get("bucketed")
     ref = docs.get("reference")
     if bucketed is None or ref is None:
         raise SystemExit(f"{path}: last entry predates the bucketed "
-                         "pipeline; re-run the bench")
+                         "pipeline row on the platform's path; re-run the "
+                         "bench")
     if bucketed < ref:
         raise SystemExit(
-            f"THROUGHPUT REGRESSION: bucketed shortlist pruning "
+            f"THROUGHPUT REGRESSION: bucketed pruning "
             f"{bucketed:.2f} docs/s fell below the reference path "
             f"{ref:.2f} docs/s at the bench shape "
             f"{last.get('pruning_shape')}")
@@ -1180,7 +1180,7 @@ def main():
         f"holds={wins};"
         f"speedup={rerank['speedup_fused_over_reference']:.2f}x at "
         f"{rerank['shape']['n_q']}q x {rerank['shape']['n_docs']}docs")
-    prune_speedup = pruning["bucketed_shortlist"] / pruning["reference"]
+    prune_speedup = pruning["bucketed"] / pruning["reference"]
     common.csv_line(
         "kernel_backends/CLAIM_bucketed_pruning_2x_reference", 0.0,
         f"holds={prune_speedup >= 2.0};speedup={prune_speedup:.2f}x at "

@@ -2,14 +2,14 @@
 claim; paper: 12.0 s vs 1474.3 s per 10k docs with 10^4 samples).
 
 Measured at the paper's geometry — 180-token documents, 128-d
-embeddings, 10^4 samples — on the same device for both methods.  Two
-deviations from the paper's setup are deliberate and favor the BASELINE:
-(1) the LP is our TPU-re-engineered batched subgradient ascent (a
-contribution of this repro) rather than scipy's simplex, and (2) VP runs
-the exact single-host shortlist path rather than the fused Pallas
-kernel.  The paper's 120x therefore compresses, but VP remains an order
-of magnitude faster — and it produces a full pruning ORDER for any
-budget, where LP yields only one fixed theta-cut per run.
+embeddings, 10^4 samples — on the same device for both methods.  VP
+runs the platform's pruning scan (``shortlist_topk`` on TPU, the
+reference elsewhere).  One deviation from the paper's setup is
+deliberate and favors the BASELINE: the LP is our TPU-re-engineered
+batched subgradient ascent (a contribution of this repro) rather than
+scipy's simplex.  The paper's 120x therefore compresses, but VP remains
+an order of magnitude faster — and it produces a full pruning ORDER for
+any budget, where LP yields only one fixed theta-cut per run.
 """
 
 from __future__ import annotations
@@ -18,8 +18,12 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common
-from repro.core import baselines, voronoi
+from repro.core import baselines, pruning_pipeline, voronoi
 from repro.core.sampling import sample_sphere
+
+# Pruning rows: the two paths, and the bucketed corpus pipeline on the
+# platform's path.
+PRUNING_BACKENDS = ("reference", "shortlist_topk", "bucketed")
 
 
 def run(n_docs: int = 8, m: int = 180, dim: int = 128,
@@ -31,8 +35,7 @@ def run(n_docs: int = 8, m: int = 180, dim: int = 128,
     samples = sample_sphere(jax.random.PRNGKey(7), n_samples, dim)
 
     def vp():
-        r, e, _ = voronoi.pruning_order_batch(d, masks, samples,
-                                              shortlist=True)
+        r, e, _ = voronoi.pruning_order_batch(d, masks, samples)
         return r
 
     t_vp, _ = common.timeit(vp, repeat=1)
@@ -47,16 +50,17 @@ def run(n_docs: int = 8, m: int = 180, dim: int = 128,
 
 def run_pruning_backends(n_docs: int = 4, m: int = 48, dim: int = 128,
                          n_samples: int = 2048):
-    """End-to-end pruning throughput (docs/sec) per dispatch backend.
+    """End-to-end pruning throughput (docs/sec) per row of
+    :data:`PRUNING_BACKENDS`.
 
-    CPU-scaled shape; on CPU the fused/topk paths pay the Pallas-
-    interpreter tax per step, so their docs/sec here is a correctness-
-    priced lower bound — the number to watch on TPU where the kernels
-    compile to Mosaic.  The shortlist rows run with autotuned (K, R);
-    ``bucketed_shortlist`` is the corpus pipeline (on this full-length
-    corpus bucketing is a no-op pass-through, so the row prices the
-    pipeline overhead; see run_ragged_pruning for the raggedness win).
-    Returns {backend: docs_per_s}.
+    CPU-scaled shape; on CPU the shortlist_topk path pays the Pallas-
+    interpreter tax per step, so its docs/sec here is a correctness-
+    priced lower bound — the number to watch on TPU where the kernel
+    compiles to Mosaic.  It runs with autotuned (K, R).  ``bucketed`` is
+    the corpus pipeline on the platform's path (on this full-length
+    corpus bucketing is a no-op pass-through, so off-TPU the row prices
+    the pipeline overhead over the reference; see run_ragged_pruning
+    for the raggedness win).  Returns {row: docs_per_s}.
     """
     k = jax.random.PRNGKey(0)
     d = jax.random.normal(k, (n_docs, m, dim))
@@ -66,16 +70,15 @@ def run_pruning_backends(n_docs: int = 4, m: int = 48, dim: int = 128,
 
     out = {}
     runs = {
-        "reference": dict(backend="reference"),
-        "fused": dict(backend="fused"),
-        "shortlist": dict(shortlist=True),
-        "shortlist_topk": dict(backend="shortlist_topk"),
-        "bucketed_shortlist": dict(shortlist=True, bucketed=True),
+        "reference": lambda: voronoi.pruning_order_batch(
+            d, masks, samples, backend="reference"),
+        "shortlist_topk": lambda: voronoi.pruning_order_batch(
+            d, masks, samples, backend="shortlist_topk"),
+        "bucketed": lambda: pruning_pipeline.pruning_order_bucketed(
+            d, masks, samples),
     }
-    for name, kw in runs.items():
-        t, _ = common.timeit(
-            lambda kw=kw: voronoi.pruning_order_batch(d, masks, samples,
-                                                      **kw)[0], repeat=1)
+    for name in PRUNING_BACKENDS:
+        t, _ = common.timeit(lambda fn=runs[name]: fn()[0], repeat=1)
         out[name] = n_docs / t
     out["shape"] = dict(n_docs=n_docs, m=m, dim=dim, n_samples=n_samples)
     return out
@@ -84,7 +87,7 @@ def run_pruning_backends(n_docs: int = 4, m: int = 48, dim: int = 128,
 def run_ragged_pruning(n_docs: int = 16, m: int = 48, dim: int = 128,
                        n_samples: int = 2048, seed: int = 3):
     """Ragged-corpus pruning: flat full-`m` padding vs the length-
-    bucketed pipeline (both on the tuned dense-shortlist path).  Doc
+    bucketed pipeline (both on the platform's pruning path).  Doc
     lengths are uniform in [4, m]; the flat path pays (m-1) scan steps
     over m-wide rows for every document regardless.  Returns docs/sec
     for both plus the speedup."""
@@ -97,12 +100,10 @@ def run_ragged_pruning(n_docs: int = 16, m: int = 48, dim: int = 128,
     samples = sample_sphere(jax.random.PRNGKey(7), n_samples, dim)
 
     t_flat, _ = common.timeit(
-        lambda: voronoi.pruning_order_batch(d, masks, samples,
-                                            shortlist=True)[0], repeat=1)
+        lambda: voronoi.pruning_order_batch(d, masks, samples)[0], repeat=1)
     t_buck, _ = common.timeit(
-        lambda: voronoi.pruning_order_batch(d, masks, samples,
-                                            shortlist=True,
-                                            bucketed=True)[0], repeat=1)
+        lambda: pruning_pipeline.pruning_order_bucketed(
+            d, masks, samples)[0], repeat=1)
     return {
         "flat": n_docs / t_flat,
         "bucketed": n_docs / t_buck,
@@ -124,8 +125,7 @@ def main():
         f"holds={ratio > 5};ratio={ratio:.1f}x vs our TPU-reengineered LP "
         f"(paper reports 120x vs scipy simplex)")
     bk = run_pruning_backends()
-    for name in ("reference", "fused", "shortlist", "shortlist_topk",
-                 "bucketed_shortlist"):
+    for name in PRUNING_BACKENDS:
         common.csv_line(f"speedup/pruning_backend_{name}",
                         1e6 / bk[name],
                         f"docs_per_s={bk[name]:.2f} (48-tok docs, "

@@ -1,8 +1,8 @@
 """Backend dispatch for the two serving/pruning hot paths (DESIGN §Backends).
 
 This module is the single seam through which the algorithmic layer
-(`repro.core.voronoi`, `repro.serve.retrieval`) reaches the fused Pallas
-kernels (`repro.kernels.maxsim_top2`, `repro.kernels.colbert_maxsim`).
+(`repro.core.voronoi`, `repro.serve.retrieval`) reaches the Pallas
+kernels (`repro.kernels.maxsim_topk`, `repro.kernels.colbert_maxsim`).
 Every later scaling PR (sharded serving, multi-host pruning) plugs into
 this seam rather than into the call sites.
 
@@ -10,40 +10,30 @@ Path matrix
 -----------
 
 ======================  ==========================  =======================
-path                    what it does                when it wins
+path                    what it does                where it runs
 ======================  ==========================  =======================
-``reference``           pure-jnp oracle; caches     small problems; oracle
-                        the full (N, m) score       for parity tests; only
-                        matrix (pruning) or the     path with exact jnp
-                        4-D (n_q, n_docs, l, m)     tie-breaking *defined*
-                        einsum tensor (serving)     by construction
-``fused``               Pallas kernels; score       serving on TPU, and any
-                        *tiles* live in VMEM, the   shape where the resident
-                        big intermediates never     score matrix/tensor is
-                        reach HBM; per-step FLOPs   HBM- or memory-bound
-                        are higher (tiles are       (long docs, large
-                        recomputed), bytes are      corpora, big sample
-                        much lower                  sets)
-``shortlist``           exact top-K shortlist       single-host CPU/GPU
-(pruning only)          cache; per-step work is     pruning jobs; fastest
-                        O(N*K) instead of O(N*m)    wall-clock off-TPU, but
-                        with a periodic dense       its ``lax.top_k`` rescan
-                        ``lax.top_k`` rescan        de-partitions under
-                                                    GSPMD
-``shortlist_topk``      same shortlist algorithm,   TPU pruning (the
-(pruning only)          but the rescan runs         platform default) and
-                        through the fused           multi-host jobs: no
-                        ``maxsim_topk`` Pallas      TopK custom-call, the
-                        kernel — score tiles stay   rescan partitions over
-                        in VMEM, no (N, m) matrix   the sample/doc axes
-                        and no TopK custom-call     under GSPMD
+``reference``           pure-jnp oracle; caches     off-TPU (the platform
+                        the full (N, m) score       default there), parity
+                        matrix (pruning) or the     tests, and pruning at
+                        4-D (n_q, n_docs, l, m)     ``step_size > 1`` on
+                        einsum tensor (serving)     every platform
+``fused``               Pallas serving kernels;     serving on TPU (the
+(serving only)          score tiles live in VMEM,   platform default)
+                        the 4-D tensor never
+                        reaches HBM
+``shortlist_topk``      exact top-K shortlist       pruning on TPU (the
+(pruning only)          scan, O(N*K) a step, whose  platform default): no
+                        periodic rescan runs the    (N, m) matrix, no TopK
+                        ``maxsim_topk`` Pallas      custom-call, partitions
+                        kernel                      over samples and docs
 ======================  ==========================  =======================
 
-``resolve_backend(None)`` picks, on TPU, ``shortlist_topk`` where the
-caller allows it (pruning) and ``fused`` otherwise (serving); off-TPU it
-picks ``reference``.  The ``REPRO_BACKEND`` environment variable
-overrides (useful to force the fused path through the Pallas interpreter
-off-TPU for parity debugging).
+``resolve_backend(None)`` picks, on TPU, the kernel path the caller
+allows (``shortlist_topk`` for pruning, ``fused`` for serving) and
+``reference`` where it allows none (pruning at ``step_size > 1``);
+off-TPU it picks ``reference``.  The ``REPRO_BACKEND`` environment
+variable overrides (useful to force a kernel path through the Pallas
+interpreter off-TPU for parity debugging).
 
 ``tuned(kind, **shape)`` is the autotuner seam: call sites that used to
 hardcode block sizes / shortlist schedules ask it for a
@@ -72,7 +62,6 @@ __all__ = [
     "FUSED",
     "PRUNING",
     "SERVING",
-    "SHORTLIST",
     "SHORTLIST_TOPK",
     "default_interpret",
     "on_tpu",
@@ -85,12 +74,11 @@ __all__ = [
 
 REFERENCE = "reference"
 FUSED = "fused"
-SHORTLIST = "shortlist"
 SHORTLIST_TOPK = "shortlist_topk"
-BACKENDS = (REFERENCE, FUSED, SHORTLIST, SHORTLIST_TOPK)
-# Per-path allow sets: serving has no shortlist analogue.
+BACKENDS = (REFERENCE, FUSED, SHORTLIST_TOPK)
+# Per-path allow sets: each hot path has its oracle and one kernel path.
+PRUNING = (REFERENCE, SHORTLIST_TOPK)
 SERVING = (REFERENCE, FUSED)
-PRUNING = BACKENDS
 
 _ENV_VAR = "REPRO_BACKEND"
 
@@ -112,12 +100,14 @@ def default_interpret(interpret: bool | None = None) -> bool:
 
 
 def _platform_default(allow: tuple[str, ...]) -> str:
-    """TPU prefers the partitionable kernel paths: ``shortlist_topk``
-    where the caller supports it (pruning), else ``fused`` (serving).
-    Off-TPU the materializing reference path wins (Pallas runs through
-    the interpreter there)."""
+    """TPU takes the kernel path the caller allows: ``shortlist_topk``
+    (pruning), else ``fused`` (serving), else ``reference``.  Off-TPU
+    the materializing reference path wins (Pallas runs through the
+    interpreter there)."""
     if on_tpu():
-        return SHORTLIST_TOPK if SHORTLIST_TOPK in allow else FUSED
+        for path in (SHORTLIST_TOPK, FUSED):
+            if path in allow:
+                return path
     return REFERENCE
 
 
@@ -126,9 +116,9 @@ def resolve_backend(backend: str | None = None,
     """Resolve a user-facing ``backend=`` argument to a concrete path.
 
     Precedence: explicit argument > ``REPRO_BACKEND`` env var > platform
-    default (on TPU ``shortlist_topk`` where allowed, else ``fused``;
-    ``reference`` elsewhere).  ``allow`` restricts the valid set for
-    entry points that support fewer paths (serving has no shortlist).
+    default (:func:`_platform_default`).  ``allow`` restricts the valid
+    set for entry points that support fewer paths (``PRUNING``,
+    ``SERVING``).
     An explicit argument outside ``allow`` raises; an env-var value that
     is a *valid* backend but outside this path's ``allow`` falls back to
     the platform default (a global override must not crash paths it
@@ -148,7 +138,7 @@ def resolve_backend(backend: str | None = None,
                     f"known backend; choose one of {list(BACKENDS)}")
             if env not in allow:
                 # valid backend that doesn't exist for this path (e.g.
-                # shortlist on serving): fall back to platform default
+                # fused on pruning): fall back to platform default
                 # rather than crash paths the override can't apply to.
                 env = None
         backend = env or _platform_default(allow)

@@ -12,10 +12,10 @@ This pipeline cuts both costs:
    token count into a few padded shape buckets (power-of-two widths by
    default, so the number of distinct compiled shapes is O(log m) no
    matter how ragged the corpus is).
-2. **Within a bucket**: the shortlist scan (or whichever backend is
-   selected) is vmapped at the bucket width — a 32-token document in
-   the 32-wide bucket runs a 31-step scan over 32-token score rows
-   instead of an (m-1)-step scan over m-token rows.
+2. **Within a bucket**: the pruning scan (``shortlist_topk`` on TPU,
+   the reference elsewhere) is vmapped at the bucket width — a
+   32-token document in the 32-wide bucket runs a 31-step scan over
+   32-token score rows instead of an (m-1)-step scan over m-token rows.
 3. **Across buckets**: bucket computations are dispatched back-to-back
    without blocking — JAX's async dispatch keeps the device busy on
    bucket i while bucket i+1 is being sliced and enqueued (the
@@ -30,7 +30,7 @@ buys a smaller packed/compressed artifact at bounded score cost
 
 Exactness: a document's pruning order depends only on its own real
 tokens (dead/padded columns score ``NEG_INF`` and are never selected,
-and every backend's per-step reductions are elementwise in the padded
+and both paths' per-step reductions are elementwise in the padded
 axis), so truncating at the document's *effective length* — last alive
 position + 1, which handles scattered (non-prefix) masks too — and
 running it in a narrower bucket changes nothing.  The
@@ -239,8 +239,6 @@ def _dispatch_block(d_embs, d_masks, samples, bucket, rows, mesh,
 
 
 def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
-                           fast: bool = False, bf16_scores: bool = False,
-                           shortlist: bool = False,
                            backend: str | None = None,
                            granularity: int | str = "pow2",
                            min_width: int = 8,
@@ -271,21 +269,19 @@ def pruning_order_bucketed(d_embs, d_masks, samples, *, step_size: int = 1,
                                granularity=granularity, min_width=min_width)
     from repro.sharding.specs import data_mesh_for
     mesh = data_mesh_for(sharded, who="pruning_order_bucketed")
-    # Only non-reference backends consume the pruning tuner's knobs —
-    # skipping the warm for reference keeps measured mode
-    # (REPRO_AUTOTUNE=measure) from racing kernels nobody will run.
+    # Only the kernel scan consumes the pruning tuner's knobs — skipping
+    # the warm for reference keeps measured mode (REPRO_AUTOTUNE=measure)
+    # from racing kernels nobody will run.
     needs_tuner = (mesh is not None
                    and voronoi.resolve_pruning_backend(
-                       backend, shortlist=shortlist, fast=fast,
-                       bf16_scores=bf16_scores, step_size=step_size)
+                       backend, step_size=step_size)
                    != backend_lib.REFERENCE)
 
     # Stream buckets: slice + dispatch everything first (async dispatch
     # overlaps bucket i's compute with bucket i+1's staging — the
     # double-buffered loop), then gather.
     in_flight = []
-    kw = dict(step_size=step_size, fast=fast, bf16_scores=bf16_scores,
-              shortlist=shortlist, backend=backend)
+    kw = dict(step_size=step_size, backend=backend)
     for bucket, rows in _doc_blocks(plan, samples.shape[0]):
         with obs.span("repro.prune.dispatch", width=bucket.width,
                       docs=len(bucket.indices)):
@@ -343,9 +339,9 @@ def pool_tokens(d_embs, keep, threshold: float):
 
 
 def prune_corpus(d_embs, d_masks, samples, keep_fraction: float, *,
-                 backend: str | None = None, shortlist: bool = False,
-                 step_size: int = 1, granularity: int | str = "pow2",
-                 min_width: int = 8, sharded: bool | None = None):
+                 backend: str | None = None, step_size: int = 1,
+                 granularity: int | str = "pow2", min_width: int = 8,
+                 sharded: bool | None = None):
     """Corpus-level pruning, end to end: bucketed per-doc orders merged
     into global keep masks (§4.2) under a corpus-wide token budget.
     Returns (keep_masks (n_docs, m), ranks, errs).
@@ -356,9 +352,8 @@ def prune_corpus(d_embs, d_masks, samples, keep_fraction: float, *,
     auto/force/off policy; results are bit-identical either way."""
     with obs.span("repro.prune", docs=d_masks.shape[0]):
         ranks, errs, _ = pruning_order_bucketed(
-            d_embs, d_masks, samples, backend=backend, shortlist=shortlist,
-            step_size=step_size, granularity=granularity,
-            min_width=min_width, sharded=sharded)
+            d_embs, d_masks, samples, backend=backend, step_size=step_size,
+            granularity=granularity, min_width=min_width, sharded=sharded)
         with obs.span("repro.prune.merge") as sp:
             traces = voronoi.merge_traces()
             keep = voronoi.global_keep_masks(ranks, errs, d_masks,

@@ -74,8 +74,8 @@ def top2_scores(samples: jax.Array, d_emb: jax.Array,
                 d_mask: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Per-sample (best, second, argbest, argsecond) of samples @ d_emb.T.
 
-    This is the pure-jnp oracle for the Pallas ``maxsim_top2`` kernel and
-    the reference path of the Voronoi estimator.
+    This is the reference path of the Voronoi estimator, and at k=2 the
+    oracle of the Pallas ``maxsim_topk`` kernel.
     Shapes: samples (N, dim), d_emb (m, dim), d_mask (m,) ->
     ((N,), (N,), (N,), (N,)).
     """

@@ -1,9 +1,9 @@
 """Shape-aware autotuner for the kernel-backed hot paths (ROADMAP item).
 
 Every tunable knob of the pruning and serving paths — Pallas tile sizes
-(``block_s``/``block_t`` for the ``maxsim_top2``/``maxsim_topk``
-kernels, ``block_docs``/``block_q`` for the chunked serving sweep) and
-the shortlist algorithm's (``shortlist``, ``rescan_every``) pair — used
+(``block_s``/``block_t`` for the ``maxsim_topk`` kernel,
+``block_docs``/``block_q`` for the chunked serving sweep) and the
+shortlist algorithm's (``shortlist``, ``rescan_every``) pair — used
 to be hardcoded defaults at the call sites.  This module picks them
 from (problem shape, platform, VMEM budget) instead:
 
@@ -460,6 +460,8 @@ def _time_once(fn) -> float:
 
 
 def _measure_pruning(shape: dict, base: KernelConfig) -> KernelConfig:
+    """Race the ``shortlist_topk`` scan, the one the chip runs, at
+    K in {K/2, K, 2K} with R = K - 1."""
     import jax.numpy as jnp
 
     from repro.core import voronoi

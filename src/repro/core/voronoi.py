@@ -9,19 +9,17 @@ Casting token pruning as Voronoi-cell mass estimation:
   *  corpus-level ("global") pruning by merging per-document orders;
   *  optional step-size > 1 and beam-search variants (ablations, §6.2).
 
-Reference semantics live here in pure jnp (fixed shapes, jit/vmap/scan
-friendly).  The production TPU paths run through the Pallas kernels:
-``backend="fused"`` fuses the (best, second) reduction with the
-sample x token matmul (``repro.kernels.maxsim_top2``) so the (N, m)
-score matrix never leaves VMEM, and ``backend="shortlist_topk"`` — the
-TPU default — runs the exact top-K shortlist algorithm with its
-periodic rescan through ``repro.kernels.maxsim_topk`` (no TopK
-custom-call, partitionable under GSPMD).  Dispatch policy and the full
-path matrix live in ``repro.core.backend``; tile sizes and shortlist
-schedules come from the shape-aware autotuner (``repro.core.tuning``)
-unless pinned.  Corpus-scale jobs should use the length-bucketed
-pipeline (``repro.core.pruning_pipeline`` or
-``pruning_order_batch(bucketed=True)``).
+Pruning has two paths.  ``backend="reference"`` is the materialising
+oracle in pure jnp (fixed shapes, jit/vmap/scan friendly): the off-TPU
+default and the only path for ``step_size > 1``.
+``backend="shortlist_topk"`` — the TPU default — runs the exact top-K
+shortlist scan with its periodic rescan through the
+``repro.kernels.maxsim_topk`` Pallas kernel (no (N, m) score matrix,
+no TopK custom-call, partitionable under GSPMD).  The platform picks
+between them (:func:`resolve_pruning_backend`, ``repro.core.backend``);
+tile sizes and shortlist schedules come from the shape-aware autotuner
+(``repro.core.tuning``) unless pinned.  Corpus-scale jobs use the
+length-bucketed pipeline (``repro.core.pruning_pipeline``).
 
 Shape conventions: one document is (m, dim) + bool mask (m,); samples
 (N, dim).  Batch versions vmap over the leading doc axis.
@@ -38,8 +36,6 @@ import jax.numpy as jnp
 
 from repro.core import backend as backend_lib
 from repro.core.scoring import NEG_INF, top2_scores
-from repro.kernels.maxsim_top2.ops import (maxsim_top2_op,
-                                           maxsim_top2_update_op)
 from repro.kernels.maxsim_topk.ops import maxsim_topk_op
 
 __all__ = [
@@ -79,45 +75,6 @@ def _top2_from_scores(scores: jax.Array, alive: jax.Array) -> CellState:
     return CellState(best, second, bi, si)
 
 
-def _top2_single_pass(scores: jax.Array, alive: jax.Array) -> CellState:
-    """Single-pass top-2 via a variadic ``lax.reduce`` (§Perf iteration).
-
-    The reference path reads the (N, m) score matrix ~4x per pruning step
-    (mask materialization, argmax, masked-set, second argmax).  A custom
-    top-2 reduction monoid does it in ONE pass, and — unlike
-    ``jax.lax.top_k``, whose TopK custom-call makes GSPMD all-gather the
-    batch axis — ``lax.reduce`` partitions over the doc/sample dims.
-    Tie-breaking differs from jnp.argmax only on exactly-equal scores.
-    """
-    n, m = scores.shape
-    s = jnp.where(alive[None, :], scores, NEG_INF).astype(jnp.float32)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n, m), 1)
-    neg = jnp.full((n, m), NEG_INF, jnp.float32)
-    none = jnp.full((n, m), -1, jnp.int32)
-
-    def comb(a, b):
-        a1, ai1, a2, ai2 = a
-        b1, bi1, b2, bi2 = b
-        a_wins = a1 >= b1
-        m1 = jnp.where(a_wins, a1, b1)
-        i1 = jnp.where(a_wins, ai1, bi1)
-        # runner-up: loser of the firsts vs winner's own second
-        lose1 = jnp.where(a_wins, b1, a1)
-        lose1_i = jnp.where(a_wins, bi1, ai1)
-        own2 = jnp.where(a_wins, a2, b2)
-        own2_i = jnp.where(a_wins, ai2, bi2)
-        take_lose = lose1 >= own2
-        m2 = jnp.where(take_lose, lose1, own2)
-        i2 = jnp.where(take_lose, lose1_i, own2_i)
-        return m1, i1, m2, i2
-
-    init = (jnp.float32(NEG_INF), jnp.int32(-1), jnp.float32(NEG_INF),
-            jnp.int32(-1))
-    b1, i1, b2, i2 = jax.lax.reduce((s, idx, neg, none), init, comb,
-                                    dimensions=(1,))
-    return CellState(b1, b2, i1, i2)
-
-
 def assign_cells(d_emb: jax.Array, d_mask: jax.Array,
                  samples: jax.Array) -> CellState:
     """Initial cell assignment for all samples (Eq. 5)."""
@@ -149,9 +106,8 @@ def _select_removals(err: jax.Array, alive: jax.Array, step_size: int):
     """One Alg. 1 removal step: pick up to ``step_size`` cheapest alive
     tokens (never the last survivor) and kill them.
 
-    Returns (new_alive, sel_idx, sel_err, removed_any).  Shared verbatim
-    by the reference and fused scan bodies so selection tie-breaking
-    (lax.top_k: lowest index wins) is identical across backends.
+    Returns (new_alive, sel_idx, sel_err, removed_any).  Ties break as
+    in lax.top_k: the lowest index wins.
     """
     n_alive = jnp.sum(alive)
     k_want = jnp.minimum(step_size, jnp.maximum(n_alive - 1, 0))
@@ -182,20 +138,15 @@ def _order_to_rank(order_steps, err_steps, m: int):
     return rank, err_at_removal, order
 
 
-@functools.partial(jax.jit, static_argnames=("step_size", "single_pass",
-                                              "bf16_scores"))
-def _pruning_order_reference(d_emb, d_mask, samples, *, step_size,
-                             single_pass, bf16_scores):
+@functools.partial(jax.jit, static_argnames=("step_size",))
+def _pruning_order_reference(d_emb, d_mask, samples, *, step_size):
     """Materializing path: the (N, m) score matrix is computed once and
     stays resident; each step re-reduces the masked matrix."""
     n, m = samples.shape[0], d_emb.shape[0]
     scores = samples @ d_emb.T
     scores = jnp.where(d_mask[None, :], scores, NEG_INF)
-    if bf16_scores:
-        scores = scores.astype(jnp.bfloat16)
-    top2 = _top2_single_pass if single_pass else _top2_from_scores
 
-    state0 = top2(scores, d_mask)
+    state0 = _top2_from_scores(scores, d_mask)
     n_steps = -(-(m - 1) // step_size)  # ceil: leave >= 1 token alive
 
     def body(carry, step):
@@ -211,14 +162,13 @@ def _pruning_order_reference(d_emb, d_mask, samples, *, step_size,
         # the O(N*m) reduction entirely on steps where the removed tokens
         # were nobody's best or second (free removals — duplicate or
         # empty-cell tokens).  Under vmap (pruning_order_batch) the cond
-        # lowers to a select and both branches run — the batch path
-        # should use backend="fused" or shortlist=True instead.
+        # lowers to a select and both branches run.
         died_b = ~new_alive[st.bi]
         died_s = ~new_alive[st.si]
         affected = (died_b | died_s) & removed_any
 
         def recompute(st):
-            fresh = top2(scores, new_alive)
+            fresh = _top2_from_scores(scores, new_alive)
             return CellState(
                 best=jnp.where(affected, fresh.best, st.best),
                 second=jnp.where(affected, fresh.second, st.second),
@@ -234,48 +184,22 @@ def _pruning_order_reference(d_emb, d_mask, samples, *, step_size,
     return _order_to_rank(order_steps, err_steps, m)
 
 
-@functools.partial(jax.jit, static_argnames=("step_size", "block_s",
-                                              "block_t", "skip_unaffected"))
-def _pruning_order_fused(d_emb, d_mask, samples, *, step_size,
-                         block_s, block_t, skip_unaffected=True):
-    """Kernel-backed path: no (N, m) score matrix is ever resident.
-
-    Each step's top-2 + incremental reassignment runs through the fused
-    ``maxsim_top2`` Pallas kernel on score *tiles* (VMEM-resident, one
-    (BS, BT) block at a time).  Per-step FLOPs are higher than the
-    materializing path (tiles are recomputed from the embeddings every
-    rescan) but HBM traffic per step drops from O(N*m) score reads to
-    O((N + m) * dim) embedding reads — the regime where pruning is
-    memory-bound (long documents, large sample sets) is exactly where
-    the paper's footprint argument applies at compute time too.
-    """
-    n, m = samples.shape[0], d_emb.shape[0]
-    kern = functools.partial(maxsim_top2_op, block_s=block_s,
-                             block_t=block_t)
-    upd = functools.partial(maxsim_top2_update_op, block_s=block_s,
-                            block_t=block_t,
-                            skip_unaffected=skip_unaffected)
-    state0 = kern(samples, d_emb, d_mask)       # (best, second, bi, si)
-    n_steps = -(-(m - 1) // step_size)
-
-    def body(carry, step):
-        alive, st = carry
-        err = token_errors(CellState(*st), alive, n)
-        new_alive, sel_idx, sel_err, _ = _select_removals(
-            err, alive, step_size)
-        st2, _ = upd(samples, d_emb, new_alive, st)
-        return (new_alive, st2), (sel_idx, sel_err)
-
-    (_, _), (order_steps, err_steps) = jax.lax.scan(
-        body, (d_mask, state0), jnp.arange(n_steps))
-    return _order_to_rank(order_steps, err_steps, m)
+def resolve_pruning_backend(backend: str | None = None, *,
+                            step_size: int = 1) -> str:
+    """The one pruning-path resolution, shared by :func:`pruning_order`,
+    :func:`pruning_order_batch`, the bucketed pipeline and the launcher:
+    an explicit ``backend`` (checked against ``backend_lib.PRUNING``),
+    else ``REPRO_BACKEND``, else the platform's — ``shortlist_topk`` on
+    TPU, ``reference`` elsewhere.  ``step_size > 1`` has only the
+    reference.  Call OUTSIDE jit."""
+    allow = (backend_lib.PRUNING if step_size == 1
+             else (backend_lib.REFERENCE,))
+    return backend_lib.resolve_backend(backend, allow=allow)
 
 
 def pruning_order(d_emb: jax.Array, d_mask: jax.Array, samples: jax.Array,
-                  *, step_size: int = 1, materialize: bool = True,
-                  single_pass: bool = False, bf16_scores: bool = False,
-                  backend: str | None = None, block_s: int | None = None,
-                  block_t: int | None = None, skip_unaffected: bool = True,
+                  *, step_size: int = 1, backend: str | None = None,
+                  block_s: int | None = None, block_t: int | None = None,
                   shortlist: int | None = None,
                   rescan_every: int | None = None
                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -292,15 +216,12 @@ def pruning_order(d_emb: jax.Array, d_mask: jax.Array, samples: jax.Array,
     ``step_size > 1`` removes the ``step_size`` lowest-error tokens per
     iteration between recomputations (§6.2 "Effect of Step Size").
 
-    ``backend`` selects the execution path (``repro.core.backend``):
+    ``backend`` selects the execution path (:func:`resolve_pruning_backend`):
     ``"reference"`` keeps the (N, m) score matrix resident;
-    ``"fused"`` recomputes score tiles through the ``maxsim_top2``
-    Pallas kernel so the matrix never exists (``materialize=False`` is
-    an alias); ``"shortlist"`` / ``"shortlist_topk"`` run the exact
-    top-K shortlist algorithm with a dense or ``maxsim_topk``-kernel
-    rescan; ``None`` resolves to shortlist_topk on TPU, reference
-    elsewhere (``REPRO_BACKEND`` env var overrides).  All paths share
-    selection and reassignment semantics — orders are identical up to
+    ``"shortlist_topk"`` runs the exact top-K shortlist scan with a
+    ``maxsim_topk``-kernel rescan (:func:`pruning_order_shortlist`);
+    ``None`` resolves to shortlist_topk on TPU, reference elsewhere.
+    Both paths share selection semantics — orders are identical up to
     float tie-breaking (see tests/test_backend_dispatch.py).
 
     Tile sizes (``block_s``/``block_t``) and the shortlist schedule
@@ -309,76 +230,35 @@ def pruning_order(d_emb: jax.Array, d_mask: jax.Array, samples: jax.Array,
     seam; explicit values win.
 
     This wrapper is deliberately NOT jitted: backend resolution (env
-    var, platform, flag interplay) and autotuning happen eagerly at
-    call time; only the per-backend implementations carry jit caches.
-
-    ``skip_unaffected`` (fused path) wraps each step's kernel rescan in
-    a ``lax.cond`` that skips free removals; leave it True for single-
-    document calls — :func:`pruning_order_batch` turns it off because
-    under vmap the cond degenerates to a select that costs throughput.
+    var, platform) and autotuning happen eagerly at call time; only the
+    per-backend implementations carry jit caches.
     """
-    if backend is None and not materialize:
-        backend = backend_lib.FUSED
-    if backend is None and (single_pass or bf16_scores):
-        # These knobs name reference-path variants; honor them over the
-        # platform default instead of silently dropping them on TPU.
-        backend = backend_lib.REFERENCE
-    allow = (backend_lib.PRUNING if step_size == 1
-             else (backend_lib.REFERENCE, backend_lib.FUSED))
-    backend = backend_lib.resolve_backend(backend, allow=allow)
-    if backend in (backend_lib.SHORTLIST, backend_lib.SHORTLIST_TOPK):
-        rescan = ("topk" if backend == backend_lib.SHORTLIST_TOPK
-                  else "dense")
+    backend = resolve_pruning_backend(backend, step_size=step_size)
+    if backend == backend_lib.SHORTLIST_TOPK:
         return pruning_order_shortlist(d_emb, d_mask, samples,
-                                       bf16_scores=bf16_scores,
-                                       rescan=rescan, shortlist=shortlist,
+                                       shortlist=shortlist,
                                        rescan_every=rescan_every,
                                        block_s=block_s, block_t=block_t)
-    if backend == backend_lib.FUSED:
-        if single_pass or bf16_scores:
-            raise ValueError(
-                "single_pass/bf16_scores are reference-path knobs and "
-                "have no fused-kernel equivalent; drop them or pass "
-                "backend='reference'")
-        if block_s is None or block_t is None:
-            cfg = backend_lib.tuned("pruning", n_samples=samples.shape[0],
-                                    m=d_emb.shape[0], dim=d_emb.shape[-1])
-            block_s = cfg.block_s if block_s is None else block_s
-            block_t = cfg.block_t if block_t is None else block_t
-        return _pruning_order_fused(d_emb, d_mask, samples,
-                                    step_size=step_size, block_s=block_s,
-                                    block_t=block_t,
-                                    skip_unaffected=skip_unaffected)
     return _pruning_order_reference(d_emb, d_mask, samples,
-                                    step_size=step_size,
-                                    single_pass=single_pass,
-                                    bf16_scores=bf16_scores)
+                                    step_size=step_size)
 
 
 @functools.partial(jax.jit, static_argnames=("shortlist", "rescan_every",
-                                              "bf16_scores", "rescan",
                                               "block_s", "block_t"))
 def _pruning_order_shortlist_impl(d_emb, d_mask, samples, *, shortlist,
-                                  rescan_every, bf16_scores, rescan,
-                                  block_s, block_t):
-    """Nested-scan shortlist pruning with a pluggable rescan.
-
-    ``rescan="dense"`` caches the (N, m) score matrix once and rescans
-    with ``lax.top_k`` — fastest on a single host, but the TopK
-    custom-call de-partitions under GSPMD.  ``rescan="topk"`` recomputes
-    the rescan through the fused ``maxsim_topk`` Pallas kernel: score
-    tiles live in VMEM, no (N, m) matrix is ever cached, and the grid is
-    plain data parallelism over sample blocks — the path that shards
-    over samples/docs on a multi-host mesh.
+                                  rescan_every, block_s, block_t):
+    """Nested-scan shortlist pruning, rescanned through the fused
+    ``maxsim_topk`` Pallas kernel: score tiles live in VMEM, no (N, m)
+    matrix is ever cached, and the grid is plain data parallelism over
+    sample blocks — the path that shards over samples/docs on a
+    multi-host mesh.
 
     The inner steps are scatter-free (§Perf): validity of shortlist
     entries is maintained by compare-and-mask instead of an (N, K)
     gather + row scatter, and the Eq. 8 error accumulation is a one-hot
     matmul (an MXU-friendly segment-sum whose (N, m) one-hot is a
     transient compute intermediate, fused or freed per step — not a
-    cached score matrix).  On CPU this is ~3x the scatter-based inner at
-    the bench shape; the one-hot matmul is also bit-identical to the
-    ``.at[].add`` scatter-sum there (asserted by the parity tests).
+    cached score matrix).
 
     They are gather-free too: each sample's best token index is picked
     by a compare-and-select over the K shortlist slots (exactly one slot
@@ -392,21 +272,6 @@ def _pruning_order_shortlist_impl(d_emb, d_mask, samples, *, shortlist,
     n, m = samples.shape[0], d_emb.shape[0]
     K = min(shortlist, m)
     R = rescan_every
-    if rescan == "dense":
-        scores = samples @ d_emb.T
-        scores = jnp.where(d_mask[None, :], scores, NEG_INF)
-        if bf16_scores:
-            scores = scores.astype(jnp.bfloat16)
-
-        def rescan_fn(alive):
-            s = jnp.where(alive[None, :], scores,
-                          NEG_INF).astype(jnp.float32)
-            return jax.lax.top_k(s, K)                      # (N, K) x2
-    else:
-        def rescan_fn(alive):
-            return maxsim_topk_op(samples, d_emb, alive, k=K,
-                                  block_s=block_s, block_t=block_t)
-
     n_steps = m - 1
     n_outer = -(-n_steps // R) if n_steps else 0
     kcol = jax.lax.broadcasted_iota(jnp.int32, (n, K), 1)
@@ -414,7 +279,8 @@ def _pruning_order_shortlist_impl(d_emb, d_mask, samples, *, shortlist,
 
     def outer(carry, _):
         alive, rank, err_at, next_pos = carry
-        vals, idxs = rescan_fn(alive)       # per-sample top-K of alive
+        vals, idxs = maxsim_topk_op(        # per-sample top-K of alive
+            samples, d_emb, alive, k=K, block_s=block_s, block_t=block_t)
         valid0 = jnp.ones((n, K), bool)
 
         def inner(icarry, _):
@@ -478,22 +344,19 @@ def pruning_order_shortlist(d_emb: jax.Array, d_mask: jax.Array,
                             samples: jax.Array, *,
                             shortlist: int | None = None,
                             rescan_every: int | None = None,
-                            bf16_scores: bool = False,
-                            rescan: str = "dense",
                             block_s: int | None = None,
                             block_t: int | None = None
                             ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """EXACT fast path for :func:`pruning_order` (§Perf iteration).
+    """EXACT fast path for :func:`pruning_order` (§Perf iteration): the
+    ``shortlist_topk`` backend.
 
     The reference recomputes a masked top-2 over all m tokens for every
     sample at every removal step — O(N*m) traffic per step.  Here each
     sample instead keeps its top-`shortlist` candidate tokens; the
     per-step reduction touches only (N, K).  A full rescan runs once per
     `rescan_every` steps as the *outer* level of a nested scan (no
-    data-dependent control flow), either against a cached dense score
-    matrix (``rescan="dense"``) or through the fused ``maxsim_topk``
-    Pallas kernel (``rescan="topk"`` — the ``shortlist_topk`` backend:
-    partitionable, nothing (N, m)-shaped cached).
+    data-dependent control flow), through the fused ``maxsim_topk``
+    Pallas kernel: partitionable, nothing (N, m)-shaped cached.
 
     Exactness: between rescans at most `rescan_every - 1` tokens die, so
     the true top-2 of the alive set is always contained in the last
@@ -506,103 +369,40 @@ def pruning_order_shortlist(d_emb: jax.Array, d_mask: jax.Array,
     explicit values to pin them.  Un-jitted wrapper: knob resolution is
     a call-time decision, the impl underneath carries the jit cache.
     """
-    if rescan not in ("dense", "topk"):
-        raise ValueError(f"rescan={rescan!r}: one of ('dense', 'topk')")
-    if rescan == "topk" and bf16_scores:
-        raise ValueError(
-            "bf16_scores caches a bf16 dense score matrix and has no "
-            "topk-kernel equivalent; drop it or use rescan='dense'")
     n, m = samples.shape[0], d_emb.shape[0]
     shortlist, rescan_every, block_s, block_t = _resolve_shortlist_knobs(
         shortlist, rescan_every, block_s, block_t, n=n, m=m,
         dim=d_emb.shape[-1])
     return _pruning_order_shortlist_impl(
         d_emb, d_mask, samples, shortlist=shortlist,
-        rescan_every=rescan_every, bf16_scores=bf16_scores, rescan=rescan,
-        block_s=block_s, block_t=block_t)
-
-
-def resolve_pruning_backend(backend: str | None, *, shortlist: bool = False,
-                            fast: bool = False, bf16_scores: bool = False,
-                            step_size: int = 1) -> str:
-    """:func:`pruning_order_batch`'s backend-resolution policy
-    (shortlist aliasing, fast/bf16 implying reference, the per-
-    step_size allow set), factored out so the bucketed pipeline can
-    consult the same answer — e.g. to skip tuner warms on the
-    reference path — without drifting from the batch entry point."""
-    if backend == backend_lib.SHORTLIST:
-        backend, shortlist = None, True
-    if backend is None and shortlist and step_size == 1:
-        backend = backend_lib.SHORTLIST
-    elif backend is None and (fast or bf16_scores):
-        backend = backend_lib.REFERENCE
-    allow = (backend_lib.PRUNING if step_size == 1
-             else (backend_lib.REFERENCE, backend_lib.FUSED))
-    return backend_lib.resolve_backend(backend, allow=allow)
+        rescan_every=rescan_every, block_s=block_s, block_t=block_t)
 
 
 def pruning_order_batch(d_embs: jax.Array, d_masks: jax.Array,
                         samples: jax.Array, *, step_size: int = 1,
-                        fast: bool = False, bf16_scores: bool = False,
-                        shortlist: bool = False,
-                        backend: str | None = None,
-                        bucketed: bool = False):
+                        backend: str | None = None):
     """vmap of :func:`pruning_order` over a document batch (global pruning
     precomputation; embarrassingly parallel across the `data` mesh axis).
 
-    ``fast=True`` uses the single-pass top-2 reduction (§Perf) — exact up
-    to ties; ``bf16_scores`` halves the cached score-matrix bytes;
-    ``shortlist`` selects the dense top-K shortlist path (exact, fastest
-    on a single host, but its lax.top_k rescan de-partitions under GSPMD
-    — multi-host jobs use ``backend="shortlist_topk"``, whose
-    ``maxsim_topk`` rescan partitions; that path is also the TPU
-    default); ``backend`` forwards to :func:`pruning_order`
-    (``backend="shortlist"`` is an alias for ``shortlist=True``).
-
-    ``bucketed=True`` routes through the length-bucketed corpus pipeline
-    (``repro.core.pruning_pipeline``): documents are grouped into a few
-    padded shape buckets by real token count, so a ragged corpus stops
-    paying full-`m` padding cost for short documents and stops
-    recompiling per shape.  Results are bit-identical either way.
+    ``backend`` resolves as in :func:`pruning_order`.  Ragged corpora
+    should go through the length-bucketed pipeline
+    (``repro.core.pruning_pipeline.pruning_order_bucketed``), which
+    calls this once per width bucket; results are bit-identical.
 
     Backend resolution and autotuning happen HERE, once, before the
     vmap — never inside a trace.
     """
-    if bucketed:
-        from repro.core import pruning_pipeline
-        return pruning_pipeline.pruning_order_bucketed(
-            d_embs, d_masks, samples, step_size=step_size, fast=fast,
-            bf16_scores=bf16_scores, shortlist=shortlist, backend=backend)
-    backend = resolve_pruning_backend(backend, shortlist=shortlist,
-                                      fast=fast, bf16_scores=bf16_scores,
-                                      step_size=step_size)
+    backend = resolve_pruning_backend(backend, step_size=step_size)
     n, m, dim = samples.shape[0], d_embs.shape[1], d_embs.shape[-1]
-    if backend in (backend_lib.FUSED, backend_lib.SHORTLIST_TOPK) and (
-            fast or bf16_scores):
-        raise ValueError(
-            "fast/bf16_scores are materializing-path knobs with no "
-            f"{backend}-kernel equivalent; drop them or choose "
-            "backend='reference'/'shortlist'")
-    if backend in (backend_lib.SHORTLIST, backend_lib.SHORTLIST_TOPK):
-        rescan = ("topk" if backend == backend_lib.SHORTLIST_TOPK
-                  else "dense")
+    if backend == backend_lib.SHORTLIST_TOPK:
         K, R, bs, bt = _resolve_shortlist_knobs(None, None, None, None,
                                                 n=n, m=m, dim=dim)
         fn = lambda e, k: _pruning_order_shortlist_impl(
-            e, k, samples, shortlist=K, rescan_every=R,
-            bf16_scores=bf16_scores, rescan=rescan, block_s=bs, block_t=bt)
-    elif backend == backend_lib.FUSED:
-        cfg = backend_lib.tuned("pruning", n_samples=n, m=m, dim=dim)
-        # skip_unaffected off: under vmap the fused path's lax.cond
-        # rescan-skip lowers to a both-branches select and measurably
-        # costs throughput instead of saving it.
-        fn = lambda e, k: _pruning_order_fused(
-            e, k, samples, step_size=step_size, block_s=cfg.block_s,
-            block_t=cfg.block_t, skip_unaffected=False)
+            e, k, samples, shortlist=K, rescan_every=R, block_s=bs,
+            block_t=bt)
     else:
         fn = lambda e, k: _pruning_order_reference(
-            e, k, samples, step_size=step_size, single_pass=fast,
-            bf16_scores=bf16_scores)
+            e, k, samples, step_size=step_size)
     return jax.vmap(fn)(d_embs, d_masks)
 
 

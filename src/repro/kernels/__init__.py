@@ -1,5 +1,5 @@
 # Pallas TPU kernels for the paper's compute hot spots:
-#   maxsim_top2    — fused top-2-of-matmul (Voronoi pruning estimator)
+#   maxsim_topk    — fused top-K-of-matmul (Voronoi pruning shortlist rescan)
 #   colbert_maxsim — batched late-interaction scoring (rerank/serve)
 #   embedding_bag  — fused recsys table lookup + reduce
 #   flash_attention— online-softmax attention forward (memory-bound LM fix)

@@ -1,8 +1,7 @@
 """Fused MaxSim top-K Pallas TPU kernel — the shortlist-rescan hot path.
 
-Generalizes ``repro.kernels.maxsim_top2`` from a tile-resident top-2
-reduction to top-K: for N sample queries against m document tokens it
-returns each sample's K best dot-product scores and their token indices
+A tile-resident top-K reduction of a matmul: for N sample queries
+against m document tokens it returns each sample's K best dot-product scores and their token indices
 **without ever materializing the (N, m) score matrix in HBM**, and —
 critically — without ``jax.lax.top_k``, whose TopK custom-call makes
 GSPMD all-gather the batch axis.  This is what lets the shortlist
@@ -10,7 +9,7 @@ pruning algorithm's periodic full rescan stay partitionable over the
 sample/doc axes on a multi-host mesh (DESIGN_BACKENDS.md path matrix,
 ``shortlist_topk`` row).
 
-Tiling (same scheme as maxsim_top2):
+Tiling:
   grid = (N / BS, m / BT); the token axis is the minor (sequential) grid
   dimension, so each sample block's running (K values, K indices) pair
   lives in its output VMEM blocks across the token-tile sweep — the
@@ -48,7 +47,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.backend import default_interpret
 
-NEG = -1e30          # masked-score sentinel (matches maxsim_top2 / scoring)
+NEG = -1e30          # masked-score sentinel (matches core.scoring)
 RETIRED = -2e30      # strictly below NEG: a retired entry never re-picked
 IDX_SENTINEL_PAD = 0x7FFFFFFF
 

@@ -3,8 +3,7 @@
 ``maxsim_topk_op`` selects the compiled Pallas TPU kernel on TPU
 backends and the interpret-mode kernel elsewhere (bit-identical
 semantics).  It is the rescan primitive of the ``shortlist_topk``
-pruning path (`repro.core.voronoi.pruning_order_shortlist` with
-``rescan="topk"``): unlike ``jax.lax.top_k`` — whose TopK custom-call
+pruning path (`repro.core.voronoi.pruning_order_shortlist`): unlike ``jax.lax.top_k`` — whose TopK custom-call
 de-partitions the batch axis under GSPMD — the kernel's grid is plain
 data parallelism over sample blocks, so the shortlist algorithm stays
 shardable over samples/docs on a multi-host mesh.

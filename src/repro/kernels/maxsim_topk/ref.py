@@ -3,9 +3,9 @@
 Given samples S (N, dim), tokens D (m, dim) and an alive mask (m,),
 return each sample's top-k scores and token indices of S @ D.T over
 alive tokens via ``jax.lax.top_k`` on the materialized masked score
-matrix — sorted descending, ties to the lowest index.  This is exactly
-the rescan the shortlist pruning path performs (dense mode); the kernel
-must match it bit-for-bit, ties included.
+matrix — sorted descending, ties to the lowest index.  The kernel, which
+is the shortlist pruning path's rescan, must match it bit-for-bit, ties
+included.
 """
 
 from __future__ import annotations

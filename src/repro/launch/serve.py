@@ -211,6 +211,10 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
                     max_batch: int = 8):
     if replicas < 1:
         raise ValueError(f"--replicas {replicas} < 1")
+    # --backend names one path; pruning and serving each take it only
+    # when it is one of theirs, else their platform default.
+    prune_backend = backend if backend in backend_lib.PRUNING else None
+    serve_backend = backend if backend in backend_lib.SERVING else None
     if route != "exhaustive" and not index_dir:
         raise ValueError(f"--route {route} needs --index-dir: the routing "
                          "table is an artifact sidecar")
@@ -267,11 +271,11 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
             print(f"[serve] sharded pruning over data={data_mesh.shape['data']}")
             prune_ctx = shlib.axis_rules({"__mesh__": data_mesh})
         print(f"[serve] pruning backend: "
-              f"{voronoi.resolve_pruning_backend(backend)}")
+              f"{voronoi.resolve_pruning_backend(prune_backend)}")
         with prune_ctx:
             pruned = prune_index(d_emb, d_mask, keep_fraction,
                                  n_samples=n_samples_for(preset, arch),
-                                 backend=backend)
+                                 backend=prune_backend)
         keep = pruned.keep
         if pool_threshold:
             # Token pooling (Clavié et al.): merge near-duplicate kept
@@ -334,8 +338,6 @@ def serve_retrieval(keep_fraction: float = 0.5, n_queries: int = 32,
                   f"{routing.n_buckets} buckets x "
                   f"{routing.n_centroids} centroids "
                   f"(epoch {routing.epoch})")
-    # shortlist is a pruning-only path; serving falls back to the default.
-    serve_backend = backend if backend in backend_lib.SERVING else None
     # --mesh host: every local device on the candidates axis; the server
     # closures trace under serve_rules, so the streaming top-k merge
     # shards each capacity bucket and all-gathers only (n_q, k)
@@ -620,9 +622,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--backend", default=None,
                     choices=list(backend_lib.BACKENDS),
-                    help="pruning/scoring path (default: shortlist_topk "
-                         "pruning + fused serving on TPU, reference "
-                         "elsewhere; see repro.core.backend)")
+                    help="pruning/scoring path: 'reference' for both, "
+                         "'shortlist_topk' for pruning, 'fused' for "
+                         "serving; the other path keeps its default "
+                         "(shortlist_topk pruning + fused serving on "
+                         "TPU, reference elsewhere; see repro.core.backend)")
     ap.add_argument("--index-dir", default=None,
                     help="packed-index artifact directory: load and serve "
                          "if one exists there, else prune -> pack -> save "

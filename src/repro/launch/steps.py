@@ -646,25 +646,10 @@ def _colbert_cell(entry, shape, mesh, multi_pod, variant):
         nd, m = dims["docs_per_block"], dims["doc_len"]
         N, dim = dims["n_samples"], dims["out_dim"]
         from repro.core import voronoi
-        # §Perf variants:
-        #  "fused_top2"       — single-pass lax.reduce top-2
-        #  "fused_top2_bf16"  — + bf16 score cache
-        #  "shortlist[_bf16]" — dense top-K shortlist (REFUTED under
-        #                       GSPMD: lax.top_k all-gathers the doc axis)
-        #  "shortlist_topk"   — shortlist rescanned through the
-        #                       maxsim_topk Pallas kernel: no TopK
-        #                       custom-call, partitions over docs/samples
-        topk = variant == "shortlist_topk"
-        fast = variant.startswith("fused_top2")
-        shortl = variant.startswith("shortlist") and not topk
-        bf16 = variant.endswith("bf16")
 
-        def fn(d_embs, d_masks, samples):
+        def fn(d_embs, d_masks, samples):     # the platform's pruning scan
             with shlib.axis_rules(rules):
-                return voronoi.pruning_order_batch(
-                    d_embs, d_masks, samples, fast=fast, bf16_scores=bf16,
-                    shortlist=shortl,
-                    backend="shortlist_topk" if topk else None)
+                return voronoi.pruning_order_batch(d_embs, d_masks, samples)
 
         args = (_sds((nd, m, dim), F32), _sds((nd, m), jnp.bool_),
                 _sds((N, dim), F32))

@@ -167,7 +167,7 @@ def check_prune_parity():
         for got in (auto, forced):
             for a, b in zip(ref, got):
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for kw in (dict(shortlist=True), dict(granularity=6)):
+    for kw in (dict(backend="shortlist_topk"), dict(granularity=6)):
         ref = pruning_pipeline.pruning_order_bucketed(d, masks, S, **kw)
         with axis_rules({"__mesh__": mesh}):
             got = pruning_pipeline.pruning_order_bucketed(d, masks, S, **kw)

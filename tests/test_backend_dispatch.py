@@ -1,9 +1,11 @@
 """Backend-dispatch seam: fused/chunked paths vs reference oracles.
 
 Covers the contract the dispatch layer (repro.core.backend) promises:
-  * pruning_order(backend="fused") orders are IDENTICAL to the reference
-    path (same selection + reassignment semantics, lax.top_k lowest-index
-    tie-breaking shared by construction);
+  * pruning_order(backend="shortlist_topk") orders are IDENTICAL to the
+    reference path (the shortlist scan is exact; lowest-index
+    tie-breaking on both);
+  * the removed pruning paths are refused by name, and step_size > 1
+    resolves to the reference on every platform;
   * chunked search()/maxsim_scores(backend="fused") match the reference
     einsum path, including padded/ragged masks and query masks;
   * the compiled fused serving HLO contains NO 4-D (n_q, n_docs, l, m)
@@ -48,58 +50,27 @@ def _corpus(seed, n_docs, m, dim, ragged=True):
 
 
 class TestPruningBackendParity:
-    @sweep(n_cases=8, seed=0, m=[6, 16, 23], dim=[4, 8],
-           n_real=[None, 5], step=[1, 2])
-    def test_fused_order_identical_to_reference(self, m, dim, n_real, step):
-        if n_real is not None and n_real > m:
-            n_real = m
-        d, mask = _doc(m * dim + step, m, dim, n_real=n_real)
-        S = sampling.sample_sphere(jax.random.PRNGKey(1), 800, dim)
-        r_ref, e_ref, o_ref = voronoi.pruning_order(
-            d, mask, S, step_size=step, backend="reference")
-        r_f, e_f, o_f = voronoi.pruning_order(
-            d, mask, S, step_size=step, backend="fused")
-        np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_f))
-        np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_f))
-        fin = np.isfinite(np.asarray(e_ref))
-        assert (fin == np.isfinite(np.asarray(e_f))).all()
-        np.testing.assert_allclose(np.asarray(e_ref)[fin],
-                                   np.asarray(e_f)[fin], atol=1e-6)
-
     def test_fused_batch_ragged_masks(self):
-        """vmapped fused path over docs of very different real lengths,
-        including a one-token document (nothing to remove)."""
+        """vmapped shortlist_topk path over docs of very different real
+        lengths, including a one-token document (nothing to remove)."""
         d, masks = _corpus(3, 6, 12, 8)
         masks = masks.at[0].set(jnp.arange(12) < 1)   # degenerate doc
         S = sampling.sample_sphere(jax.random.PRNGKey(2), 600, 8)
-        r_ref, e_ref, _ = voronoi.pruning_order_batch(d, masks, S)
-        r_f, e_f, _ = voronoi.pruning_order_batch(d, masks, S,
-                                                  backend="fused")
-        np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_f))
+        r_ref, e_ref, _ = voronoi.pruning_order_batch(
+            d, masks, S, backend="reference")
+        r_t, e_t, _ = voronoi.pruning_order_batch(d, masks, S,
+                                                  backend="shortlist_topk")
+        np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_t))
         # degenerate doc: sole real token survives with rank m, err inf
-        assert bool(jnp.isinf(e_f[0, 0]))
-
-    def test_materialize_false_aliases_fused(self):
-        d, mask = _doc(5, 10, 8)
-        S = sampling.sample_sphere(jax.random.PRNGKey(3), 500, 8)
-        r_a, _, o_a = voronoi.pruning_order(d, mask, S, materialize=False)
-        r_b, _, o_b = voronoi.pruning_order(d, mask, S, backend="fused")
-        np.testing.assert_array_equal(np.asarray(r_a), np.asarray(r_b))
-        np.testing.assert_array_equal(np.asarray(o_a), np.asarray(o_b))
-
-    def test_shortlist_backend_delegates(self):
-        d, mask = _doc(9, 14, 8)
-        S = sampling.sample_sphere(jax.random.PRNGKey(7), 600, 8)
-        r_a, _, o_a = voronoi.pruning_order(d, mask, S, backend="shortlist")
-        r_b, _, o_b = voronoi.pruning_order_shortlist(d, mask, S)
-        np.testing.assert_array_equal(np.asarray(r_a), np.asarray(r_b))
-        np.testing.assert_array_equal(np.asarray(o_a), np.asarray(o_b))
+        assert int(r_t[0, 0]) == 12
+        assert bool(jnp.isinf(e_t[0, 0]))
 
     @sweep(n_cases=6, seed=5, m=[6, 16, 23], dim=[4, 8], n_real=[None, 5])
     def test_shortlist_topk_identical_to_reference(self, m, dim, n_real):
         """The kernel-rescan shortlist path (shortlist_topk backend) is
-        the same exact algorithm: orders/ranks identical to the
-        reference, errs identical to the dense shortlist bit-for-bit."""
+        the same exact algorithm: orders, and the ranks of removed
+        tokens, identical to the reference; errors equal to f32
+        rounding (the two sum a token's gaps in different orders)."""
         if n_real is not None and n_real > m:
             n_real = m
         d, mask = _doc(m * dim + 1, m, dim, n_real=n_real)
@@ -108,25 +79,30 @@ class TestPruningBackendParity:
                                                     backend="reference")
         r_t, e_t, o_t = voronoi.pruning_order(d, mask, S,
                                               backend="shortlist_topk")
-        r_d, e_d, o_d = voronoi.pruning_order(d, mask, S,
-                                              backend="shortlist")
         n_rm = int(jnp.sum(mask)) - 1
-        np.testing.assert_array_equal(np.asarray(o_ref)[:n_rm],
-                                      np.asarray(o_t)[:n_rm])
-        np.testing.assert_array_equal(np.asarray(r_t), np.asarray(r_d))
-        np.testing.assert_array_equal(np.asarray(e_t), np.asarray(e_d))
-        np.testing.assert_array_equal(np.asarray(o_t), np.asarray(o_d))
+        removed = np.asarray(o_ref)[:n_rm]
+        np.testing.assert_array_equal(removed, np.asarray(o_t)[:n_rm])
+        np.testing.assert_array_equal(np.asarray(r_ref)[removed],
+                                      np.asarray(r_t)[removed])
+        np.testing.assert_allclose(np.asarray(e_ref)[removed],
+                                   np.asarray(e_t)[removed], atol=1e-6)
 
     def test_shortlist_topk_batch_ragged(self):
+        """Batch path, ragged masks: ranks and orders equal the
+        reference's."""
         d, masks = _corpus(13, 5, 12, 8)
         S = sampling.sample_sphere(jax.random.PRNGKey(10), 500, 8)
-        out_d = voronoi.pruning_order_batch(d, masks, S, shortlist=True)
-        out_t = voronoi.pruning_order_batch(d, masks, S,
-                                            backend="shortlist_topk")
-        for a, b in zip(out_d, out_t):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        r_ref, _, o_ref = voronoi.pruning_order_batch(d, masks, S,
+                                                      backend="reference")
+        r_t, _, o_t = voronoi.pruning_order_batch(d, masks, S,
+                                                  backend="shortlist_topk")
+        np.testing.assert_array_equal(np.asarray(r_ref), np.asarray(r_t))
+        n_rm = np.asarray(masks.sum(1)) - 1
+        for i, n in enumerate(n_rm):
+            np.testing.assert_array_equal(np.asarray(o_ref)[i, :n],
+                                          np.asarray(o_t)[i, :n])
 
-    @pytest.mark.parametrize("backend", ["shortlist", "shortlist_topk"])
+    @pytest.mark.parametrize("backend", ["shortlist_topk"])
     def test_batch_identical_to_reference_at_exact_ties(self, backend):
         """Every token is duplicated and every score is a small dyadic
         number, exact whatever the summation order, so shortlist slots
@@ -153,34 +129,49 @@ class TestPruningBackendParity:
     def test_no_full_m_topk_in_shortlist_topk_hlo(self):
         """Acceptance criterion: the compiled shortlist-on-maxsim_topk
         path contains no full-m lax.top_k — neither the (N, m) top_k op
-        in the lowering nor a TopK custom-call over f32[N, m] in the
-        compiled module — while the dense shortlist provably does (the
-        GSPMD de-partitioning culprit)."""
+        in the lowering nor a TopK custom-call on an f32[N, m] operand in
+        the compiled module — while a bare lax.top_k over an (N, m)
+        array provably matches both patterns (the GSPMD de-partitioning
+        culprit)."""
         n, m, dim = 300, 23, 8
         d, mask = _doc(21, m, dim)
         S = sampling.sample_sphere(jax.random.PRNGKey(11), n, dim)
 
-        def texts(rescan):
-            fn = jax.jit(lambda dd, kk, ss:
-                         voronoi._pruning_order_shortlist_impl(
-                             dd, kk, ss, shortlist=8, rescan_every=7,
-                             bf16_scores=False, rescan=rescan,
-                             block_s=64, block_t=16))
-            lowered = fn.lower(d, mask, S)
+        def texts(fn, *args):
+            lowered = jax.jit(fn).lower(*args)
             return lowered.as_text(), lowered.compile().as_text()
 
+        def full_m_topk_call(compiled):
+            # the printer may leave operand shapes off the call line:
+            # look each operand's shape up at its definition
+            shape_of = dict(re.findall(
+                r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\])", compiled,
+                re.M))
+            for ln in compiled.splitlines():
+                call = re.search(r"custom-call\(([^)]*)\)", ln)
+                if call is None or 'custom_call_target="TopK"' not in ln:
+                    continue
+                for op in call.group(1).split(","):
+                    name = op.split()[-1] if op.split() else ""
+                    if (f"[{n},{m}]" in op
+                            or shape_of.get(name) == f"f32[{n},{m}]"):
+                        return True
+            return False
+
         low_pat = re.compile(rf"top_k[^\n]*{n}x{m}x")
-        dense_low, dense_comp = texts("dense")
+        dense_low, dense_comp = texts(
+            lambda ss, dd: jax.lax.top_k(ss @ dd.T, 8), S, d)
         assert low_pat.search(dense_low), \
-            "oracle changed: dense shortlist lowering lost its top_k"
-        assert any("TopK" in ln and f"[{n},{m}]" in ln
-                   for ln in dense_comp.splitlines()), \
-            "oracle changed: dense compiled module lost the TopK call"
-        topk_low, topk_comp = texts("topk")
+            "oracle changed: a bare (N, m) top_k lowers without top_k"
+        assert full_m_topk_call(dense_comp), \
+            "oracle changed: a bare (N, m) top_k compiles without TopK"
+        topk_low, topk_comp = texts(
+            lambda dd, kk, ss: voronoi._pruning_order_shortlist_impl(
+                dd, kk, ss, shortlist=8, rescan_every=7, block_s=64,
+                block_t=16), d, mask, S)
         assert not low_pat.search(topk_low), \
             "shortlist_topk lowering still carries a full-m top_k"
-        assert not any("TopK" in ln and f"[{n},{m}]" in ln
-                       for ln in topk_comp.splitlines()), \
+        assert not full_m_topk_call(topk_comp), \
             "shortlist_topk compiled module still calls full-m TopK"
 
     def test_no_gather_in_shortlist_scan(self):
@@ -192,8 +183,8 @@ class TestPruningBackendParity:
         S = sampling.sample_sphere(jax.random.PRNGKey(12), 64, 8)
         fn = jax.jit(jax.vmap(
             lambda dd, kk: voronoi._pruning_order_shortlist_impl(
-                dd, kk, S, shortlist=8, rescan_every=7, bf16_scores=False,
-                rescan="dense", block_s=64, block_t=16)))
+                dd, kk, S, shortlist=8, rescan_every=7, block_s=64,
+                block_t=16)))
         text = fn.lower(d, masks).as_text()
         assert "while" in text, "oracle changed: the scan is not lowered"
         assert '"stablehlo.gather"' not in text
@@ -202,49 +193,83 @@ class TestPruningBackendParity:
     def test_conflicting_knobs_rejected(self):
         d, mask = _doc(9, 10, 8)
         S = sampling.sample_sphere(jax.random.PRNGKey(8), 200, 8)
-        with pytest.raises(ValueError, match="reference-path knobs"):
-            voronoi.pruning_order(d, mask, S, backend="fused",
-                                  single_pass=True)
+        # the kernel scan removes one token a step
         with pytest.raises(ValueError, match="backend"):
-            voronoi.pruning_order(d, mask, S, backend="shortlist",
+            voronoi.pruning_order(d, mask, S, backend="shortlist_topk",
                                   step_size=2)
-        # knobs + unresolved backend prefer reference over platform default
-        r_k, _, o_k = voronoi.pruning_order(d, mask, S, single_pass=True)
-        r_r, _, o_r = voronoi.pruning_order(d, mask, S, single_pass=True,
+        # step_size > 1 with an unresolved backend takes the reference
+        r_k, _, o_k = voronoi.pruning_order(d, mask, S, step_size=2)
+        r_r, _, o_r = voronoi.pruning_order(d, mask, S, step_size=2,
                                             backend="reference")
         np.testing.assert_array_equal(np.asarray(r_k), np.asarray(r_r))
+        np.testing.assert_array_equal(np.asarray(o_k), np.asarray(o_r))
+
+    @pytest.mark.parametrize("entry", ["pruning_order", "prune_corpus"])
+    @pytest.mark.parametrize("removed", ["fused", "shortlist"])
+    def test_removed_backend_names_refused(self, entry, removed):
+        """The deleted pruning paths are refused by name, and the error
+        names the two that remain."""
+        from repro.core import pruning_pipeline
+        d, masks = _corpus(19, 2, 8, 4)
+        S = sampling.sample_sphere(jax.random.PRNGKey(13), 64, 4)
+        with pytest.raises(ValueError,
+                           match=r"\['reference', 'shortlist_topk'\]"):
+            if entry == "pruning_order":
+                voronoi.pruning_order(d[0], masks[0], S, backend=removed)
+            else:
+                pruning_pipeline.prune_corpus(d, masks, S, 0.5,
+                                              backend=removed)
+
+    def test_step_size_above_one_resolves_to_reference_on_tpu(
+            self, monkeypatch):
+        """On TPU the platform default is the kernel scan, which removes
+        one token a step; ``step_size > 1`` still resolves to the
+        reference there and returns its answer."""
+        monkeypatch.delenv("REPRO_BACKEND", raising=False)
+        d, mask = _doc(23, 12, 8)
+        S = sampling.sample_sphere(jax.random.PRNGKey(14), 300, 8)
+        want = voronoi.pruning_order(d, mask, S, step_size=2,
+                                     backend="reference")
+        monkeypatch.setattr(backend_lib, "on_tpu", lambda: True)
+        assert voronoi.resolve_pruning_backend() == "shortlist_topk"
+        assert voronoi.resolve_pruning_backend(step_size=2) == "reference"
+        got = voronoi.pruning_order(d, mask, S, step_size=2)
+        for a, b in zip(want, got):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_keep_masks_and_global_pruning_agree(self):
-        """End of the pruning pipeline: global keep masks built from fused
-        orders == built from reference orders."""
+        """End of the pruning pipeline: global keep masks built from
+        shortlist_topk orders == built from reference orders."""
         d, masks = _corpus(7, 5, 10, 8)
         S = sampling.sample_sphere(jax.random.PRNGKey(4), 700, 8)
-        out_ref = voronoi.pruning_order_batch(d, masks, S)
-        out_f = voronoi.pruning_order_batch(d, masks, S, backend="fused")
+        out_ref = voronoi.pruning_order_batch(d, masks, S,
+                                              backend="reference")
+        out_t = voronoi.pruning_order_batch(d, masks, S,
+                                            backend="shortlist_topk")
         for frac in (0.3, 0.7):
             k_ref = voronoi.global_keep_masks(out_ref[0], out_ref[1],
                                               masks, frac)
-            k_f = voronoi.global_keep_masks(out_f[0], out_f[1], masks, frac)
+            k_t = voronoi.global_keep_masks(out_t[0], out_t[1], masks, frac)
             np.testing.assert_array_equal(np.asarray(k_ref),
-                                          np.asarray(k_f))
+                                          np.asarray(k_t))
 
 
 class TestShortlistBoundary:
     @sweep(n_cases=6, seed=2, m=[9, 16, 24], dim=[4, 8],
-           rescan=[2, 4, 7])
-    def test_exact_at_minimal_shortlist(self, m, dim, rescan):
+           every=[2, 4, 7])
+    def test_exact_at_minimal_shortlist(self, m, dim, every):
         """Exactness proof edge: shortlist == rescan_every + 1 keeps the
         true top-2 inside the shortlist between rescans — the order must
         equal the reference for the MINIMAL legal K, not just K=16."""
-        K = rescan + 1
+        K = every + 1
         if K > m:
             return
-        d, mask = _doc(m + dim + rescan, m, dim)
+        d, mask = _doc(m + dim + every, m, dim)
         S = sampling.sample_sphere(jax.random.PRNGKey(5), 900, dim)
         r_ref, _, o_ref = voronoi.pruning_order(d, mask, S,
                                                 backend="reference")
         r_sl, _, o_sl = voronoi.pruning_order_shortlist(
-            d, mask, S, shortlist=K, rescan_every=rescan)
+            d, mask, S, shortlist=K, rescan_every=every)
         np.testing.assert_array_equal(np.asarray(o_ref[:m - 1]),
                                       np.asarray(o_sl[:m - 1]))
         # ranks agree on removed tokens (survivor conventions differ:
@@ -351,9 +376,15 @@ class TestBackendResolution:
             os.environ["REPRO_BACKEND"] = "shortlist_topk"
             assert backend_lib.resolve_backend(None) == "shortlist_topk"
             # valid name outside this path's allow-set: platform default
-            os.environ["REPRO_BACKEND"] = "shortlist"
             assert backend_lib.resolve_backend(
                 None, allow=backend_lib.SERVING) in backend_lib.SERVING
+            os.environ["REPRO_BACKEND"] = "fused"
+            assert backend_lib.resolve_backend(
+                None, allow=backend_lib.PRUNING) in backend_lib.PRUNING
+            # a removed name is no backend at all: loud failure
+            os.environ["REPRO_BACKEND"] = "shortlist"
+            with pytest.raises(ValueError, match="REPRO_BACKEND"):
+                backend_lib.resolve_backend(None)
             # typo: loud failure everywhere
             os.environ["REPRO_BACKEND"] = "fusedd"
             with pytest.raises(ValueError, match="REPRO_BACKEND"):
@@ -386,8 +417,10 @@ class TestBackendResolution:
         with pytest.raises(ValueError, match="backend"):
             backend_lib.resolve_backend("nope")
         with pytest.raises(ValueError, match="backend"):
-            backend_lib.resolve_backend("shortlist",
-                                        allow=("reference", "fused"))
+            backend_lib.resolve_backend("shortlist_topk",
+                                        allow=backend_lib.SERVING)
+        with pytest.raises(ValueError, match="backend"):
+            backend_lib.resolve_backend("fused", allow=backend_lib.PRUNING)
 
     def test_default_interpret_policy(self):
         assert backend_lib.default_interpret(True) is True

@@ -74,7 +74,7 @@ class TestEndToEndRetrieval:
         index = TokenIndex.build(c.d_embs, c.d_masks)
         samples = sample_sphere(jax.random.PRNGKey(1), 3000, 16)
         ranks, errs, _ = voronoi.pruning_order_batch(c.d_embs, c.d_masks,
-                                                     samples, fast=True)
+                                                     samples)
         keep = voronoi.global_keep_masks(ranks, errs, c.d_masks, 0.5)
 
         def ndcg(k):
@@ -121,7 +121,7 @@ class TestEndToEndRetrieval:
         c = corpus
         samples = sample_sphere(jax.random.PRNGKey(3), 3000, 16)
         ranks, errs, _ = voronoi.pruning_order_batch(c.d_embs, c.d_masks,
-                                                     samples, fast=True)
+                                                     samples)
         mes, nds = [], []
         index = TokenIndex.build(c.d_embs, c.d_masks)
         for b in (0.8, 0.6, 0.4, 0.2):
@@ -137,13 +137,12 @@ class TestEndToEndRetrieval:
         assert fit["slope"] < 0
 
     def test_fast_and_reference_orders_agree(self, corpus):
+        """The fast path (the shortlist_topk scan) removes tokens in the
+        reference's order on the retrieval corpus."""
         c = corpus
         samples = sample_sphere(jax.random.PRNGKey(4), 1000, 16)
-        r_ref, e_ref, _ = voronoi.pruning_order_batch(
-            c.d_embs[:8], c.d_masks[:8], samples)
-        r_fast, e_fast, _ = voronoi.pruning_order_batch(
-            c.d_embs[:8], c.d_masks[:8], samples, fast=True)
-        assert bool((r_ref == r_fast).all())
+        r_ref, _, _ = voronoi.pruning_order_batch(
+            c.d_embs[:8], c.d_masks[:8], samples, backend="reference")
         r_sl, _, _ = voronoi.pruning_order_batch(
-            c.d_embs[:8], c.d_masks[:8], samples, shortlist=True)
+            c.d_embs[:8], c.d_masks[:8], samples, backend="shortlist_topk")
         assert bool((r_ref == r_sl).all())

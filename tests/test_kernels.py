@@ -14,93 +14,9 @@ from repro.kernels.colbert_maxsim.ref import (colbert_maxsim_multi_ref,
                                               colbert_maxsim_ref)
 from repro.kernels.embedding_bag.ops import embedding_bag_op
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
-from repro.kernels.maxsim_top2.ops import (maxsim_top2_op,
-                                           maxsim_top2_update_op,
-                                           voronoi_errors_fused)
-from repro.kernels.maxsim_top2.ref import maxsim_top2_ref
 from repro.kernels.maxsim_topk.ops import maxsim_topk_op
 from repro.kernels.maxsim_topk.ref import maxsim_topk_ref
-from repro.core import voronoi, sampling
-
-
-class TestMaxSimTop2:
-    @sweep(n_cases=12, seed=0,
-           N=[16, 100, 256, 513], m=[8, 37, 128, 200],
-           dim=[8, 32, 128], dtype=["float32", "bfloat16"])
-    def test_matches_oracle(self, N, m, dim, dtype):
-        k = jax.random.PRNGKey(N * m + dim)
-        k1, k2, k3 = jax.random.split(k, 3)
-        dt = jnp.dtype(dtype)
-        S = jax.random.normal(k1, (N, dim)).astype(dt)
-        D = jax.random.normal(k2, (m, dim)).astype(dt)
-        alive = jax.random.bernoulli(k3, 0.8, (m,))
-        alive = alive.at[0].set(True).at[m // 2].set(True)
-        b, s, bi, si = maxsim_top2_op(S, D, alive)
-        rb, rs, rbi, rsi = maxsim_top2_ref(S, D, alive)
-        tol = 1e-4 if dtype == "float32" else 5e-2
-        np.testing.assert_allclose(np.asarray(b), np.asarray(rb), atol=tol,
-                                   rtol=tol)
-        np.testing.assert_allclose(np.asarray(s), np.asarray(rs), atol=tol,
-                                   rtol=tol)
-        if dtype == "float32":
-            assert bool((bi == rbi).all())
-            assert bool((si == rsi).all())
-
-    @sweep(n_cases=4, seed=3, block_s=[32, 256], block_t=[32, 128])
-    def test_block_shape_invariance(self, block_s, block_t):
-        k = jax.random.PRNGKey(0)
-        S = jax.random.normal(k, (200, 16))
-        D = jax.random.normal(jax.random.fold_in(k, 1), (100, 16))
-        alive = jnp.ones((100,), bool)
-        b, s, bi, si = maxsim_top2_op(S, D, alive, block_s=block_s,
-                                      block_t=block_t)
-        rb, rs, rbi, rsi = maxsim_top2_ref(S, D, alive)
-        np.testing.assert_allclose(np.asarray(b), np.asarray(rb), atol=1e-4)
-        assert bool((bi == rbi).all())
-        assert bool((si == rsi).all())
-
-    @sweep(n_cases=6, seed=7, m=[16, 100], kill=[1, 3, 9],
-           block_t=[32, 128])
-    def test_update_op_matches_fresh_rescan(self, m, kill, block_t):
-        """Alive-mask-update entry == full rescan under the shrunk mask."""
-        k = jax.random.PRNGKey(m + kill)
-        S = jax.random.normal(k, (64, 16))
-        D = jax.random.normal(jax.random.fold_in(k, 1), (m, 16))
-        alive = jnp.ones((m,), bool)
-        prev = maxsim_top2_op(S, D, alive, block_t=block_t)
-        dead = jax.random.choice(jax.random.fold_in(k, 2),
-                                 m - 1, (kill,), replace=False) + 1
-        alive2 = alive.at[dead].set(False)
-        (b, s, bi, si), affected = maxsim_top2_update_op(
-            S, D, alive2, prev, block_t=block_t)
-        rb, rs, rbi, rsi = maxsim_top2_ref(S, D, alive2)
-        np.testing.assert_allclose(np.asarray(b), np.asarray(rb), atol=1e-4)
-        np.testing.assert_allclose(np.asarray(s), np.asarray(rs), atol=1e-4)
-        assert bool((bi == rbi).all())
-        # unaffected samples kept their previous state bit-for-bit
-        keep = ~np.asarray(affected)
-        np.testing.assert_array_equal(np.asarray(b)[keep],
-                                      np.asarray(prev[0])[keep])
-
-    def test_fused_errors_match_reference_estimator(self):
-        k = jax.random.PRNGKey(5)
-        D = jax.random.normal(k, (24, 16))
-        D = D / jnp.linalg.norm(D, axis=-1, keepdims=True)
-        mask = jnp.arange(24) < 20
-        S = sampling.sample_sphere(jax.random.PRNGKey(6), 2000, 16)
-        fused = voronoi_errors_fused(S, D, mask)
-        ref = voronoi.estimate_errors(D, mask, S)
-        np.testing.assert_allclose(np.asarray(fused[:20]),
-                                   np.asarray(ref[:20]), atol=1e-5)
-        assert bool(jnp.all(jnp.isinf(fused[20:])))
-
-    def test_single_alive_token(self):
-        S = jax.random.normal(jax.random.PRNGKey(0), (32, 8))
-        D = jax.random.normal(jax.random.PRNGKey(1), (5, 8))
-        alive = jnp.zeros((5,), bool).at[2].set(True)
-        b, s, bi, si = maxsim_top2_op(S, D, alive)
-        assert bool((bi == 2).all())
-        assert bool((s <= -1e29).all())  # no second-best exists
+from repro.core.scoring import top2_scores
 
 
 class TestMaxSimTopK:
@@ -159,13 +75,14 @@ class TestMaxSimTopK:
             maxsim_topk_op(S, D, jnp.ones((5,), bool), k=6)
 
     def test_top2_agreement(self):
-        """k=2 specializes to exactly what maxsim_top2 computes."""
+        """k=2 specializes to exactly the Voronoi estimator's top-2
+        (``core.scoring.top2_scores``: best, second and their tokens)."""
         S = jax.random.normal(jax.random.PRNGKey(2), (50, 16))
         D = jax.random.normal(jax.random.PRNGKey(3), (40, 16))
         alive = jax.random.bernoulli(jax.random.PRNGKey(4), 0.7, (40,))
         alive = alive.at[0].set(True).at[1].set(True)
         v, i = maxsim_topk_op(S, D, alive, k=2, block_t=16)
-        b, s, bi, si = maxsim_top2_op(S, D, alive, block_t=16)
+        b, s, bi, si = top2_scores(S, D, alive)
         np.testing.assert_array_equal(np.asarray(v[:, 0]), np.asarray(b))
         np.testing.assert_array_equal(np.asarray(v[:, 1]), np.asarray(s))
         np.testing.assert_array_equal(np.asarray(i[:, 0]), np.asarray(bi))

@@ -3,7 +3,7 @@ bit-identical-parity contract against the flat `pruning_order_batch`.
 
 The pipeline's whole value is that bucketing is a pure execution-shape
 change: (ranks, errs, orders) must match the unbucketed batch path BIT
-for BIT on ragged corpora, for every backend, including degenerate
+for BIT on ragged corpora, on both pruning paths, including degenerate
 documents (one-token, fully masked) and step_size > 1.
 """
 
@@ -15,6 +15,8 @@ import pytest
 from _proptest import sweep
 from repro.core import pruning_pipeline as pp
 from repro.core import sampling, voronoi
+
+TOPK = "shortlist_topk"     # the pruning scan the chip runs
 
 
 def _ragged_corpus(seed, n_docs, m, dim):
@@ -56,10 +58,8 @@ class TestBucketPlan:
 
 class TestBucketedParity:
     @sweep(n_cases=6, seed=1, n_docs=[5, 12], m=[10, 24, 33], dim=[4, 8],
-           backend_kw=[{}, {"shortlist": True},
-                       {"backend": "shortlist_topk"},
-                       {"backend": "fused"}, {"step_size": 3},
-                       {"fast": True}])
+           backend_kw=[{}, {"backend": "shortlist_topk"},
+                       {"step_size": 3}])
     def test_bit_identical_to_flat_batch(self, n_docs, m, dim, backend_kw):
         d, masks, _ = _ragged_corpus(n_docs * m + dim, n_docs, m, dim)
         S = sampling.sample_sphere(jax.random.PRNGKey(2), 400, dim)
@@ -68,15 +68,6 @@ class TestBucketedParity:
         for name, a, b in zip(("ranks", "errs", "orders"), flat, buck):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=f"{name} {backend_kw}")
-
-    def test_bucketed_flag_on_batch_entry(self):
-        d, masks, _ = _ragged_corpus(3, 6, 16, 8)
-        S = sampling.sample_sphere(jax.random.PRNGKey(3), 300, 8)
-        a = voronoi.pruning_order_batch(d, masks, S, shortlist=True)
-        b = voronoi.pruning_order_batch(d, masks, S, shortlist=True,
-                                        bucketed=True)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
     def test_scattered_non_prefix_masks(self):
         """Masks need not be prefix-padded (e.g. stopword filtering
@@ -93,8 +84,8 @@ class TestBucketedParity:
         S = sampling.sample_sphere(jax.random.PRNGKey(18), 400, 8)
         eff = pp.effective_lengths(masks)
         assert int(eff[0]) == m
-        flat = voronoi.pruning_order_batch(d, masks, S, shortlist=True)
-        buck = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
+        flat = voronoi.pruning_order_batch(d, masks, S, backend=TOPK)
+        buck = pp.pruning_order_bucketed(d, masks, S, backend=TOPK)
         for name, a, b in zip(("ranks", "errs", "orders"), flat, buck):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=name)
@@ -105,8 +96,8 @@ class TestBucketedParity:
         masks = masks.at[0].set(False)                    # 0 real tokens
         masks = masks.at[1].set(jnp.arange(20) < 1)       # 1 real token
         S = sampling.sample_sphere(jax.random.PRNGKey(4), 300, 8)
-        flat = voronoi.pruning_order_batch(d, masks, S, shortlist=True)
-        buck = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
+        flat = voronoi.pruning_order_batch(d, masks, S, backend=TOPK)
+        buck = pp.pruning_order_bucketed(d, masks, S, backend=TOPK)
         for a, b in zip(flat, buck):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         # conventions: nothing removed, rank sentinel m, err inf
@@ -137,8 +128,8 @@ class TestBucketedParity:
         d, masks, n_real = _ragged_corpus(9, 8, 24, 8)
         plan = pp.bucket_plan(np.asarray(n_real), 24)
         S = sampling.sample_sphere(jax.random.PRNGKey(7), 300, 8)
-        a = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
-        b = pp.pruning_order_bucketed(d, masks, S, shortlist=True,
+        a = pp.pruning_order_bucketed(d, masks, S, backend=TOPK)
+        b = pp.pruning_order_bucketed(d, masks, S, backend=TOPK,
                                       plan=plan)
         for x, y in zip(a, b):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
@@ -164,10 +155,10 @@ class TestDocBlocks:
         d, masks, _ = _ragged_corpus(13, 11, 24, 8)
         masks = masks.at[2].set(False)                    # 0 real tokens
         S = sampling.sample_sphere(jax.random.PRNGKey(9), 300, 8)
-        whole = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
+        whole = pp.pruning_order_bucketed(d, masks, S, backend=TOPK)
         monkeypatch.setattr(pp, "pruning_docs_per_block",
                             lambda n, w: docs_per_block)
-        blocked = pp.pruning_order_bucketed(d, masks, S, shortlist=True)
+        blocked = pp.pruning_order_bucketed(d, masks, S, backend=TOPK)
         # Orders are exact; errors only to f32 rounding: XLA vectorizes
         # a different batch size differently.
         np.testing.assert_array_equal(np.asarray(whole[0]),
@@ -184,8 +175,8 @@ class TestPruneCorpus:
         S = sampling.sample_sphere(jax.random.PRNGKey(8), 500, 8)
         for frac in (0.3, 0.7):
             keep, ranks, errs = pp.prune_corpus(d, masks, S, frac,
-                                                shortlist=True)
-            flat = voronoi.pruning_order_batch(d, masks, S, shortlist=True)
+                                                backend=TOPK)
+            flat = voronoi.pruning_order_batch(d, masks, S, backend=TOPK)
             ref = voronoi.global_keep_masks(flat[0], flat[1], masks, frac)
             np.testing.assert_array_equal(np.asarray(keep), np.asarray(ref))
             # budget + per-doc floor invariants survive the bucketing

@@ -21,7 +21,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.tuning import heuristic_config
 from repro.kernels.colbert_maxsim import colbert_maxsim as cm
-from repro.kernels.maxsim_top2.maxsim_top2 import maxsim_top2
 from repro.kernels.maxsim_topk.maxsim_topk import maxsim_topk
 
 DIM, L, N_Q, N_DOCS, N_SAMPLES = 128, 32, 16, 64, 1024
@@ -117,19 +116,16 @@ def test_colbert_maxsim_residual_rerank(one_chip):
                 _spec(one_chip, (N_DOCS, m), jnp.bool_))
 
 
-@pytest.mark.parametrize("kernel", ("top2", "topk"))
-def test_pruning_kernels(one_chip, kernel):
-    for m in CAPS:
-        cfg = heuristic_config("pruning", platform="tpu",
-                               n_samples=N_SAMPLES, m=m, dim=DIM)
-        kw = dict(block_s=cfg.block_s, block_t=cfg.block_t, interpret=False)
-        if kernel == "top2":
-            fn = lambda s, t, a: maxsim_top2(s, t, a, **kw)
-        else:
-            fn = lambda s, t, a: maxsim_topk(s, t, a, k=cfg.shortlist, **kw)
-        _assert_compiles(fn, _spec(one_chip, (N_SAMPLES, DIM)),
-                         _spec(one_chip, (m, DIM)),
-                         _spec(one_chip, (m,), jnp.bool_))
+@pytest.mark.parametrize("m", CAPS)
+def test_pruning_kernels(one_chip, m):
+    cfg = heuristic_config("pruning", platform="tpu", n_samples=N_SAMPLES,
+                           m=m, dim=DIM)
+    _assert_compiles(
+        lambda s, t, a: maxsim_topk(s, t, a, k=cfg.shortlist,
+                                    block_s=cfg.block_s, block_t=cfg.block_t,
+                                    interpret=False),
+        _spec(one_chip, (N_SAMPLES, DIM)), _spec(one_chip, (m, DIM)),
+        _spec(one_chip, (m,), jnp.bool_))
 
 
 def test_pruning_kernel_at_document_length_300(one_chip):
@@ -169,8 +165,7 @@ def test_shortlist_scan_has_no_gather(one_chip, monkeypatch):
                            m=m, dim=DIM)
     fn = jax.vmap(lambda e, k, s: voronoi._pruning_order_shortlist_impl(
         e, k, s, shortlist=cfg.shortlist, rescan_every=cfg.rescan_every,
-        bf16_scores=False, rescan="topk", block_s=cfg.block_s,
-        block_t=cfg.block_t), in_axes=(0, 0, None))
+        block_s=cfg.block_s, block_t=cfg.block_t), in_axes=(0, 0, None))
     # The kernel resolves compiled-vs-interpreted at trace time; trace
     # it as on the chip, and keep that trace out of later CPU tests.
     monkeypatch.setattr(backend_lib, "on_tpu", lambda: True)

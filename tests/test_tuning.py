@@ -176,6 +176,26 @@ class TestMeasuredMode:
         cfg = tuning.tune("pruning", n_samples=64, m=12, dim=4)
         cfg.validate()
 
+    def test_race_times_the_kernel_scan(self, monkeypatch):
+        """The measured race times the pruning scan the chip runs: every
+        candidate rescans through the ``maxsim_topk`` kernel."""
+        seen = []
+        real = voronoi.maxsim_topk_op
+
+        def counting(*args, **kw):
+            seen.append(kw["k"])
+            return real(*args, **kw)
+
+        monkeypatch.setattr(voronoi, "maxsim_topk_op", counting)
+        jax.clear_caches()              # the kernel is called while tracing
+        shape = dict(n_samples=72, m=11, dim=4)
+        base = tuning.heuristic_config("pruning", **shape)
+        best = tuning._measure_pruning(shape, base)
+        raced = {max(2, min(k, 11)) for k in
+                 (base.shortlist // 2, base.shortlist, base.shortlist * 2)}
+        assert set(seen) == raced
+        assert best.shortlist in raced
+
     def test_env_var_enables(self, monkeypatch):
         hits = []
         monkeypatch.setattr(tuning, "_measure_pruning",
@@ -208,7 +228,8 @@ class TestConsumersConsultTuner:
         d = jax.random.normal(jax.random.PRNGKey(0), (3, 14, 8)) * 0.5
         masks = jnp.arange(14)[None, :] < jnp.array([4, 14, 9])[:, None]
         S = sampling.sample_sphere(jax.random.PRNGKey(1), 300, 8)
-        out = voronoi.pruning_order_batch(d, masks, S, shortlist=True)
+        out = voronoi.pruning_order_batch(d, masks, S,
+                                          backend="shortlist_topk")
         assert "pruning" in seen
         ref = voronoi.pruning_order_batch(d, masks, S, backend="reference")
         np.testing.assert_array_equal(np.asarray(out[0]),
