@@ -57,6 +57,37 @@ class TestMaxSimTopK:
         np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
         np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
 
+    @pytest.mark.parametrize("m,k,block_t", [
+        (128, 16, 128), (180, 16, 256), (256, 16, 256), (300, 32, 384),
+        (128, 16, 32), (180, 16, 64), (300, 32, 128)])
+    @pytest.mark.parametrize("step", [1 / 8, 1 / 2])
+    def test_build_cell_shapes_bitwise(self, m, k, block_t, step):
+        """The build cells' rescans: 10,000 samples (the last 256-lane
+        sample block overhangs N) against one width bucket with the
+        tuner's K, in one token tile (the tuner's ``block_t``) and in
+        several.  Inputs on a ``step`` grid make every score exact, so
+        the matmul's accumulation order cannot differ from the oracle's;
+        the coarse grid and duplicated rows force exact ties across
+        sublane groups of 8 tokens, token tiles, and sample lane
+        columns and blocks."""
+        key = jax.random.PRNGKey(m * 131 + k)
+        S = jnp.clip(jnp.round(jax.random.normal(key, (10_000, 128)) / step),
+                     -16, 16) * step
+        D = jnp.clip(jnp.round(jax.random.normal(
+            jax.random.fold_in(key, 1), (m, 128)) / step), -16, 16) * step
+        for a, b in ((7, 8), (15, 16), (m // 2 - 1, m // 2), (0, m - 1),
+                     (block_t - 1, block_t)):
+            if b < m:
+                D = D.at[b].set(D[a])
+        for a, b in ((127, 128), (255, 256), (511, 512), (0, 9_999)):
+            S = S.at[b].set(S[a])
+        alive = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.9, (m,))
+        alive = alive.at[0].set(True).at[m - 1].set(False)
+        v, i = maxsim_topk_op(S, D, alive, k=k, block_s=256, block_t=block_t)
+        rv, ri = maxsim_topk_ref(S, D, alive, k)
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+
     def test_k_equals_m_returns_full_argsort(self):
         S = jax.random.normal(jax.random.PRNGKey(0), (33, 8))
         D = jax.random.normal(jax.random.PRNGKey(1), (12, 8))

@@ -6,8 +6,10 @@ on one device of a described ``v5e:2x2`` topology, compiles it with the
 installed TPU compiler, and asserts the program holds the compiled
 kernel (``tpu_custom_call``); the §4.2 merge, which has no kernel,
 compiles as one program.  Widths are the ColBERT serving widths
-(dim 128, query length 32) over bucket caps m in {32, 180, 256}; block
-sizes are the ones ``core.tuning`` picks on TPU.  The Pallas
+(dim 128, query length 32) over bucket caps m in {32, 180, 256}, and
+the pruning kernel's bucket widths {32, 128, 180, 256, 300} at 1,024
+and at the build cells' 10,000 samples; block sizes are the ones
+``core.tuning`` picks on TPU.  The Pallas
 interpreter cannot catch what these catch: lane/sublane misalignment,
 unsupported vector layouts, scoped-VMEM overflow.
 """
@@ -25,6 +27,7 @@ from repro.kernels.maxsim_topk.maxsim_topk import maxsim_topk
 
 DIM, L, N_Q, N_DOCS, N_SAMPLES = 128, 32, 16, 64, 1024
 CAPS = (32, 180, 256)
+PRUNING_CAPS = (32, 128, 180, 256, 300)
 CENTROIDS = 8
 
 
@@ -116,29 +119,32 @@ def test_colbert_maxsim_residual_rerank(one_chip):
                 _spec(one_chip, (N_DOCS, m), jnp.bool_))
 
 
-@pytest.mark.parametrize("m", CAPS)
-def test_pruning_kernels(one_chip, m):
-    cfg = heuristic_config("pruning", platform="tpu", n_samples=N_SAMPLES,
+def _assert_pruning_kernel_compiles(one_chip, n_samples, m):
+    cfg = heuristic_config("pruning", platform="tpu", n_samples=n_samples,
                            m=m, dim=DIM)
     _assert_compiles(
         lambda s, t, a: maxsim_topk(s, t, a, k=cfg.shortlist,
                                     block_s=cfg.block_s, block_t=cfg.block_t,
                                     interpret=False),
-        _spec(one_chip, (N_SAMPLES, DIM)), _spec(one_chip, (m, DIM)),
+        _spec(one_chip, (n_samples, DIM)), _spec(one_chip, (m, DIM)),
         _spec(one_chip, (m,), jnp.bool_))
+
+
+@pytest.mark.parametrize("m", (*CAPS, 128))
+def test_pruning_kernels(one_chip, m):
+    _assert_pruning_kernel_compiles(one_chip, N_SAMPLES, m)
+
+
+@pytest.mark.parametrize("m", PRUNING_CAPS)
+def test_pruning_kernels_at_10k_samples(one_chip, m):
+    """The build cells' 10,000 samples: the last sample block overhangs N."""
+    _assert_pruning_kernel_compiles(one_chip, 10_000, m)
 
 
 def test_pruning_kernel_at_document_length_300(one_chip):
     """GTE-ModernColBERT's 300-token documents prune in a bucket 300 wide,
     which is not a multiple of the kernel's token block."""
-    cfg = heuristic_config("pruning", platform="tpu", n_samples=N_SAMPLES,
-                           m=300, dim=DIM)
-    _assert_compiles(
-        lambda s, t, a: maxsim_topk(s, t, a, k=cfg.shortlist,
-                                    block_s=cfg.block_s, block_t=cfg.block_t,
-                                    interpret=False),
-        _spec(one_chip, (N_SAMPLES, DIM)), _spec(one_chip, (300, DIM)),
-        _spec(one_chip, (300,), jnp.bool_))
+    _assert_pruning_kernel_compiles(one_chip, N_SAMPLES, 300)
 
 
 @pytest.mark.parametrize("n_docs,m", [(64, 180), (64, 300), (8192, 180)])
